@@ -195,6 +195,8 @@ def csv_rows(quick: bool = False) -> list[tuple]:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default=None, choices=[None, "single", "multi"])
     ap.add_argument("--routing", action="store_true",

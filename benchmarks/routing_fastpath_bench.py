@@ -316,6 +316,8 @@ def _per_cell_gate(results: dict, section: str) -> list[dict]:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny grid for CI (no acceptance gates)")

@@ -181,6 +181,8 @@ def csv_rows(quick: bool = True) -> list[tuple]:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="short sweep (same parity gate)")

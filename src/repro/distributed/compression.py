@@ -84,10 +84,6 @@ def cross_pod_mean_int8(mesh, axis: str = "pod"):
         # replication check off: the int8 gather+mean provably replicates
         # the result across the pod axis, but the varying-manual-axes
         # checker can't see through the quantize/dequantize round trip.
-        return _shard_map(body, mesh, (specs,), specs)(grads)
+        return jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                             out_specs=specs, check_vma=False)(grads)
     return transform
-
-
-def _shard_map(body, mesh, in_specs, out_specs):
-    from repro.distributed.sharding import shard_map_compat
-    return shard_map_compat(body, mesh, in_specs, out_specs)

@@ -141,25 +141,6 @@ def named_sharding(*logical_axes: Optional[str]) -> Optional[NamedSharding]:
     return NamedSharding(mesh, spec_for(*logical_axes))
 
 
-def shard_map_compat(body, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: ``jax.shard_map(check_vma=...)``
-    on current jax, ``jax.experimental.shard_map(check_rep=...)`` on 0.4.x.
-
-    Replication checking is off in both spellings: the callers here
-    (int8 gather+mean in ``distributed.compression``, the sharded
-    dispatch backend) provably replicate what they claim, but the
-    varying-manual-axes checker can't see through quantize/dequantize
-    round trips or gathered top-k.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_experimental
-    return sm_experimental(body, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # Parameter sharding by tree-path convention
 # ---------------------------------------------------------------------------

@@ -21,8 +21,16 @@ Gini/cumulative normalizers use the per-row count. All four metrics are
 always emitted, so the router's metric choice is a column select — never
 a recompile.
 
-Grid: row tiles; one [rows_tile, K] VMEM block, four VPU reductions, one
-[rows_tile, 4] store. K=100 pads to 128 lanes with mask-aware reductions.
+Grid: row tiles; one [rows_tile, Kpad] VMEM block, four VPU reductions
+and one MXU pass, one lane-dense [rows_tile, 128] store whose first four
+columns are the metrics (sliced outside the kernel). K=100 pads to 128
+lanes with mask-aware reductions. Every intermediate stays 2-D
+(``keepdims=True``): TPU vectors are (sublane, lane) tiles.
+
+The CDF is a running sum, which Mosaic has no lowering for; it is the
+matmul ``prob @ U`` with U the [Kpad, Kpad] upper-triangular ones, at
+``Precision.HIGHEST``. A default-precision f32 dot on a TPU is one bf16
+pass, whose rounding can move cumulative-k by a whole context.
 """
 
 from __future__ import annotations
@@ -35,6 +43,7 @@ from jax.experimental import pallas as pl
 
 DEFAULT_ROW_TILE = 8
 _EPS = 1e-12
+_LANES = 128
 
 METRIC_COLUMNS = ("area", "cumulative", "entropy", "gini")
 
@@ -51,7 +60,7 @@ def _skew_kernel(s_ref, nv_ref, o_ref, *, p_cdf: float):
     s_hi = jnp.max(jnp.where(valid, s, -jnp.inf), axis=1, keepdims=True)
     s_lo = jnp.min(jnp.where(valid, s, jnp.inf), axis=1, keepdims=True)
     norm = jnp.where(valid, (s - s_lo) / (s_hi - s_lo + _EPS), 0.0)
-    area = jnp.sum(norm, axis=1)
+    area = jnp.sum(norm, axis=1, keepdims=True)
 
     # probability normalization (shift only if negatives, like the oracle)
     shifted = jnp.where(valid, s - jnp.minimum(s_lo, 0.0), 0.0)
@@ -59,24 +68,32 @@ def _skew_kernel(s_ref, nv_ref, o_ref, *, p_cdf: float):
     prob = shifted / (total + _EPS)
 
     # cumulative-k: scores arrive descending, so CDF = running sum
-    cdf = jnp.cumsum(prob, axis=1)
+    upper = (jax.lax.broadcasted_iota(jnp.int32, (kpad, kpad), 0)
+             <= jax.lax.broadcasted_iota(jnp.int32, (kpad, kpad), 1))
+    cdf = jax.lax.dot(prob, upper.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
     below = jnp.where(valid, (cdf < p_cdf - _EPS).astype(jnp.float32), 0.0)
-    cum_k = jnp.minimum(jnp.sum(below, axis=1) + 1.0, nvf[:, 0])
+    cum_k = jnp.minimum(jnp.sum(below, axis=1, keepdims=True) + 1.0, nvf)
 
     # entropy (bits) — jnp.log2 to match the oracle's formulation exactly
     plogp = jnp.where(prob > _EPS, prob * jnp.log2(prob + _EPS), 0.0)
-    entropy = -jnp.sum(plogp, axis=1)
+    entropy = -jnp.sum(plogp, axis=1, keepdims=True)
 
     # gini: paper weight (n - asc_rank + 1) over ascending-sorted data is
     # just (col + 1) for descending-sorted data
     weight = jnp.where(valid, (col + 1).astype(jnp.float32), 0.0)
-    weighted = jnp.sum(weight * shifted, axis=1)
-    tot = total[:, 0]
-    n1 = jnp.maximum(nvf[:, 0], 1.0)
-    gini = (n1 + 1.0 - 2.0 * weighted / (tot + _EPS)) / n1
+    weighted = jnp.sum(weight * shifted, axis=1, keepdims=True)
+    n1 = jnp.maximum(nvf, 1.0)
+    gini = (n1 + 1.0 - 2.0 * weighted / (total + _EPS)) / n1
     gini = jnp.clip(gini, 0.0, 1.0)
 
-    o_ref[...] = jnp.stack([area, cum_k, entropy, gini], axis=1)
+    # one lane-dense row per query: column c holds METRIC_COLUMNS[c]
+    out_col = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    out = jnp.zeros((rows, _LANES), jnp.float32)
+    for c, metric in enumerate((area, cum_k, entropy, gini)):
+        out = jnp.where(out_col == c, metric, out)
+    o_ref[...] = out
 
 
 @functools.partial(jax.jit,
@@ -108,8 +125,8 @@ def skew_metrics(scores_desc: jax.Array,
         grid=(bpad // row_tile,),
         in_specs=[pl.BlockSpec((row_tile, kpad), lambda i: (i, 0)),
                   pl.BlockSpec((row_tile, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((row_tile, 4), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bpad, 4), jnp.float32),
+        out_specs=pl.BlockSpec((row_tile, _LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bpad, _LANES), jnp.float32),
         interpret=interpret,
     )(s, nv)
-    return out[:b]
+    return out[:b, :len(METRIC_COLUMNS)]

@@ -14,8 +14,20 @@ The GPU baseline (SubgraphRAG) runs this as separate GEMM + bias + GEMM
 launches with the hidden activations round-tripping through HBM.
 
 Grid: (queries, triple_tiles); both parallel.
-VMEM: W1_t [Dt, H] + tile [128, Dt] + h [128, H] — for Dt=1156, H=1024
-(paper-scale) ≈ 5 MiB, within budget.
+Layout (a TPU block's last two dims must be divisible by (8, 128) or equal
+the array's): the per-query bias is a [Q, 1, H] array read as a squeezed
+(None, 1, H) block; scores are written as one lane-dense [1, tile] row of
+a [Q, 1, N] array; ``w2`` enters as a [1, H] row and ``b2`` as an SMEM
+scalar. The second layer is an NT dot of the ``w2`` row against ``h``,
+which gives the [1, tile] score row directly.
+VMEM: W1_t [Dt, H] + tile [128, Dt] + h [128, H] + two [1, H] rows — for
+Dt=1156, H=1024 (paper-scale) ≈ 5 MiB, within budget.
+
+Precision: every dot here and in the XLA reference (`ref.py`) runs at
+``Precision.HIGHEST``. A TPU f32 dot at default precision is a single bf16
+pass, and two programs that round differently can swap near-tied
+candidates in the top-k; full f32 on both sides keeps them within f32
+rounding of each other.
 """
 
 from __future__ import annotations
@@ -25,23 +37,59 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TILE = 128
+PRECISION = jax.lax.Precision.HIGHEST
+
+_NT = (((1,), (1,)), ((), ()))  # contract the last dim of both operands
 
 
 def _score_kernel(t_ref, qb_ref, w1_ref, w2_ref, b2_ref, o_ref):
     t = t_ref[...].astype(jnp.float32)            # [tile, Dt]
     w1 = w1_ref[...].astype(jnp.float32)          # [Dt, H]
-    qb = qb_ref[...].astype(jnp.float32)          # [1, H] query bias
-    h = jax.lax.dot(t, w1, preferred_element_type=jnp.float32) + qb
+    qb = qb_ref[...]                              # [1, H] query bias
+    h = jax.lax.dot(t, w1, precision=PRECISION,
+                    preferred_element_type=jnp.float32) + qb
     h = jnp.maximum(h, 0.0)
-    w2 = w2_ref[...].astype(jnp.float32)          # [H, 1]
-    score = jax.lax.dot(h, w2, preferred_element_type=jnp.float32)
-    o_ref[...] = (score[:, 0] + b2_ref[0]).astype(o_ref.dtype)[None]
+    w2 = w2_ref[...].astype(jnp.float32)          # [1, H]
+    score = jax.lax.dot_general(w2, h, _NT, precision=PRECISION,
+                                preferred_element_type=jnp.float32)
+    o_ref[...] = score + b2_ref[0]                # [1, tile]
 
 
 def _pad_to(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
+
+
+def _score_call(triple_feats, t_spec: pl.BlockSpec, query_emb, w1_t, w1_q,
+                b1, w2, b2, n_out: int, tile: int,
+                interpret: bool) -> jax.Array:
+    """The pallas_call both entry points share; they differ only in how a
+    (query, tile) grid cell finds its triple tile (``t_spec``).
+    Returns [Q, n_out] scores."""
+    q_count = query_emb.shape[0]
+    dt, h_dim = w1_t.shape
+    # per-query first-layer bias, computed once (tiny GEMM): [Q, 1, H]
+    q_bias = (jnp.dot(query_emb.astype(jnp.float32),
+                      w1_q.astype(jnp.float32), precision=PRECISION)
+              + b1.astype(jnp.float32))[:, None, :]
+    out = pl.pallas_call(
+        _score_kernel,
+        grid=(q_count, n_out // tile),
+        in_specs=[
+            t_spec,
+            pl.BlockSpec((None, 1, h_dim), lambda iq, it: (iq, 0, 0)),
+            pl.BlockSpec((dt, h_dim), lambda iq, it: (0, 0)),
+            pl.BlockSpec((1, h_dim), lambda iq, it: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ],
+        out_specs=pl.BlockSpec((None, 1, tile), lambda iq, it: (iq, 0, it)),
+        out_shape=jax.ShapeDtypeStruct((q_count, 1, n_out), jnp.float32),
+        interpret=interpret,
+    )(triple_feats, q_bias, w1_t, w2.reshape(1, h_dim),
+      b2.astype(jnp.float32))
+    return out[:, 0, :]
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -55,28 +103,11 @@ def triple_score(triple_feats: jax.Array, query_emb: jax.Array,
     b1: [H]; w2: [H, 1]; b2: [1]  ->  scores [Q, N].
     """
     n, dt = triple_feats.shape
-    q_count = query_emb.shape[0]
-    h_dim = w1_t.shape[1]
     if n % tile:
         raise ValueError(f"N={n} not divisible by tile={tile}")
-    # Per-query first-layer bias, computed once (tiny GEMM).
-    q_bias = (query_emb.astype(jnp.float32) @ w1_q.astype(jnp.float32)
-              + b1.astype(jnp.float32))                       # [Q, H]
-    grid = (q_count, n // tile)
-    return pl.pallas_call(
-        _score_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, dt), lambda iq, it: (it, 0)),
-            pl.BlockSpec((1, h_dim), lambda iq, it: (iq, 0)),
-            pl.BlockSpec((dt, h_dim), lambda iq, it: (0, 0)),
-            pl.BlockSpec((h_dim, 1), lambda iq, it: (0, 0)),
-            pl.BlockSpec((1,), lambda iq, it: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda iq, it: (iq, it)),
-        out_shape=jax.ShapeDtypeStruct((q_count, n), jnp.float32),
-        interpret=interpret,
-    )(triple_feats, q_bias, w1_t, w2, b2)
+    t_spec = pl.BlockSpec((tile, dt), lambda iq, it: (it, 0))
+    return _score_call(triple_feats, t_spec, query_emb, w1_t, w1_q, b1, w2,
+                       b2, n, tile, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -89,37 +120,19 @@ def triple_score_batched(triple_feats: jax.Array, query_emb: jax.Array,
 
     triple_feats: [B, N, Dt]; query_emb: [B, Dq] -> scores [B, N].
 
-    Same kernel body as :func:`triple_score` — the [B, N, Dt] batch is
-    flattened to [B*Npad, Dt] and the block index map walks each query's
-    own slice (block ``iq * tiles_per_query + it``), so weights and the
-    per-query bias stay VMEM-resident exactly as in the shared-candidate
-    variant. N is padded up to the tile size internally; padded rows are
-    zero-feature triples whose scores are sliced off before returning
-    (callers masking ragged candidate sets still pass their own
-    ``n_cand`` downstream — see `repro.core.router.route_retrieved`).
+    Same kernel body as :func:`triple_score` — the triple block is a
+    squeezed (None, tile, Dt) window of query ``iq``'s own [Npad, Dt]
+    slice, so weights and the per-query bias stay VMEM-resident exactly as
+    in the shared-candidate variant. N is padded up to the tile size
+    internally; padded rows are zero-feature triples whose scores are
+    sliced off before returning (callers masking ragged candidate sets
+    still pass their own ``n_cand`` downstream — see
+    `repro.core.router.route_retrieved`).
     """
-    b, n, dt = triple_feats.shape
-    h_dim = w1_t.shape[1]
+    _, n, dt = triple_feats.shape
     npad = _pad_to(n, tile)
     feats = jnp.pad(triple_feats, ((0, 0), (0, npad - n), (0, 0)))
-    flat = feats.reshape(b * npad, dt)
-    q_bias = (query_emb.astype(jnp.float32) @ w1_q.astype(jnp.float32)
-              + b1.astype(jnp.float32))                      # [B, H]
-    tiles_per_query = npad // tile
-    grid = (b, tiles_per_query)
-    out = pl.pallas_call(
-        _score_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, dt),
-                         lambda iq, it: (iq * (npad // tile) + it, 0)),
-            pl.BlockSpec((1, h_dim), lambda iq, it: (iq, 0)),
-            pl.BlockSpec((dt, h_dim), lambda iq, it: (0, 0)),
-            pl.BlockSpec((h_dim, 1), lambda iq, it: (0, 0)),
-            pl.BlockSpec((1,), lambda iq, it: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda iq, it: (iq, it)),
-        out_shape=jax.ShapeDtypeStruct((b, npad), jnp.float32),
-        interpret=interpret,
-    )(flat, q_bias, w1_t, w2, b2)
+    t_spec = pl.BlockSpec((None, tile, dt), lambda iq, it: (iq, it, 0))
+    out = _score_call(feats, t_spec, query_emb, w1_t, w1_q, b1, w2, b2,
+                      npad, tile, interpret)
     return out[:, :n]

@@ -15,20 +15,9 @@ import jax
 
 
 def make_auto_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types across jax versions.
-
-    Newer jax exposes ``jax.sharding.AxisType`` and ``make_mesh`` takes
-    ``axis_types``; older versions (<= 0.4.x) have neither — but Auto is
-    their only behavior, so plain ``make_mesh`` is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:  # make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -9,8 +9,6 @@ drift-aware streaming recalibration, per-tier micro-batch queues) ->
 engines generating real tokens, with cost/latency telemetry. The policy
 is pure data: the driver prints the spec JSON a replica would need to
 run the identical router.
-On TPU the tier configs switch to the assigned archs (yi-6b small /
-gemma-7b medium / internlm2-20b large) on the production mesh.
 """
 
 from __future__ import annotations
@@ -23,6 +21,8 @@ import numpy as np
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=40)
     ap.add_argument("--budget", type=float, default=0.4,
