@@ -59,7 +59,7 @@ def roofline_table(recs: list[dict]) -> str:
              "|---|---|---|---|---|---|---|---|---|"]
     for r in recs:
         if not r.get("ok") or "roofline" not in r:
-            continue                # measured-only records (select_depths)
+            continue
         rl = r["roofline"]
         lines.append(
             f"| {r['arch']} | {r['shape']} | {rl['compute_s']:.3e} | "
@@ -118,80 +118,24 @@ def routing_record(batch: int, n_cand: int) -> dict:
                           "total_bytes": lc["collective_total_bytes"],
                           "n_ops": lc["collective_n_ops"]}
     rec["roofline"] = roofline_terms(rec)
-    # MEASURED wall time next to the modeled terms: profile the program
-    # we just compiled (no second compile) — block_until_ready best-of,
-    # via the obs plane's profiling hook.
-    from repro.obs import profile_program
-    prof = profile_program(fn, args, name="route_retrieved",
-                           shape=rec["shape"], iters=5, compiled=compiled)
-    rec["measured"] = prof.to_dict()
     return rec
-
-
-def select_depths_record(batch: int) -> dict:
-    """Profile the jitted depth-selection program (`core.router.
-    select_depths` — the adaptive_depth policy's second routed axis)."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.core.router import select_depths
-    from repro.obs import profile_program
-
-    rng = np.random.default_rng(0)
-    args = (jnp.asarray(rng.uniform(0, 8, batch).astype(np.float32)),
-            jnp.asarray([4.0, 6.0], jnp.float32),
-            jnp.asarray([25, 50, 100], jnp.int32))
-    prof = profile_program(lambda d, c, o: select_depths(d, c, o), args,
-                           name="select_depths", shape=f"B{batch}",
-                           iters=5)
-    return {"arch": "select_depths", "shape": f"B{batch}", "ok": True,
-            "measured": prof.to_dict()}
-
-
-def measured_table(recs: list[dict]) -> str:
-    lines = ["| program | shape | compile (s) | wall (s) | GFLOP/s | "
-             "GiB/s |",
-             "|---|---|---|---|---|---|"]
-    for r in recs:
-        m = r.get("measured")
-        if not m:
-            continue
-        lines.append(
-            f"| {m['name']} | {m['shape']} | {m['compile_s']:.2f} | "
-            f"{m['wall_s']:.3e} | {m['achieved_gflops']:.2f} | "
-            f"{m['achieved_gbps'] / 1.073741824:.2f} |")
-    return "\n".join(lines)
 
 
 def routing_roofline(shapes=ROUTING_SHAPES) -> list[dict]:
     recs = [routing_record(b, n) for b, n in shapes]
-    recs.append(select_depths_record(batch=shapes[-1][0]))
     print("## Roofline (fused retrieve-to-decision program)\n")
     print(roofline_table(recs))
-    print("\n## Measured (block_until_ready best-of, this host)\n")
-    print(measured_table(recs))
     return recs
 
 
 def csv_rows(quick: bool = False) -> list[tuple]:
-    """``benchmarks.run`` harness entry: measured + modeled numbers for
-    the serving device programs (one shape when ``quick``)."""
+    """``benchmarks.run`` harness entry: the modeled bottleneck of the
+    serving device program (one shape when ``quick``)."""
     shapes = ROUTING_SHAPES[:1] if quick else ROUTING_SHAPES
-    rows: list[tuple] = []
-    for rec in routing_roofline(shapes):
-        m = rec.get("measured") or {}
-        tag = f"roofline/{rec['arch']}/{rec['shape']}"
-        if m:
-            rows.append((f"{tag}/wall_s", round(m["wall_s"], 6),
-                         "measured block_until_ready best-of"))
-            rows.append((f"{tag}/achieved_gbps",
-                         round(m["achieved_gbps"], 3),
-                         "HLO bytes_accessed / measured wall"))
-        rl = rec.get("roofline")
-        if rl:
-            rows.append((f"{tag}/bound", rl["dominant"],
-                         "modeled bottleneck (compute/memory/collective)"))
-    return rows
+    return [(f"roofline/{rec['arch']}/{rec['shape']}/bound",
+             rec["roofline"]["dominant"],
+             "modeled bottleneck (compute/memory/collective)")
+            for rec in routing_roofline(shapes)]
 
 
 def main() -> None:

@@ -128,5 +128,6 @@ def skew_metrics(scores_desc: jax.Array,
         out_specs=pl.BlockSpec((row_tile, _LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bpad, _LANES), jnp.float32),
         interpret=interpret,
+        name="skew_metrics",
     )(s, nv)
     return out[:b, :len(METRIC_COLUMNS)]

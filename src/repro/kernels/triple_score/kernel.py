@@ -64,9 +64,10 @@ def _pad_to(n: int, multiple: int) -> int:
 
 def _score_call(triple_feats, t_spec: pl.BlockSpec, query_emb, w1_t, w1_q,
                 b1, w2, b2, n_out: int, tile: int,
-                interpret: bool) -> jax.Array:
+                interpret: bool, name: str) -> jax.Array:
     """The pallas_call both entry points share; they differ only in how a
-    (query, tile) grid cell finds its triple tile (``t_spec``).
+    (query, tile) grid cell finds its triple tile (``t_spec``) and in the
+    kernel's ``name``, which a profiler trace shows as its operation.
     Returns [Q, n_out] scores."""
     q_count = query_emb.shape[0]
     dt, h_dim = w1_t.shape
@@ -87,6 +88,7 @@ def _score_call(triple_feats, t_spec: pl.BlockSpec, query_emb, w1_t, w1_q,
         out_specs=pl.BlockSpec((None, 1, tile), lambda iq, it: (iq, 0, it)),
         out_shape=jax.ShapeDtypeStruct((q_count, 1, n_out), jnp.float32),
         interpret=interpret,
+        name=name,
     )(triple_feats, q_bias, w1_t, w2.reshape(1, h_dim),
       b2.astype(jnp.float32))
     return out[:, 0, :]
@@ -107,7 +109,7 @@ def triple_score(triple_feats: jax.Array, query_emb: jax.Array,
         raise ValueError(f"N={n} not divisible by tile={tile}")
     t_spec = pl.BlockSpec((tile, dt), lambda iq, it: (it, 0))
     return _score_call(triple_feats, t_spec, query_emb, w1_t, w1_q, b1, w2,
-                       b2, n, tile, interpret)
+                       b2, n, tile, interpret, "triple_score")
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -134,5 +136,5 @@ def triple_score_batched(triple_feats: jax.Array, query_emb: jax.Array,
     feats = jnp.pad(triple_feats, ((0, 0), (0, npad - n), (0, 0)))
     t_spec = pl.BlockSpec((None, tile, dt), lambda iq, it: (iq, it, 0))
     out = _score_call(feats, t_spec, query_emb, w1_t, w1_q, b1, w2, b2,
-                      npad, tile, interpret)
+                      npad, tile, interpret, "triple_score_batched")
     return out[:, :n]

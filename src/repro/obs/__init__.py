@@ -15,17 +15,16 @@ surfaces the serving stack threads through every component:
   the request-id range it covers) so tracing stays O(batches) on the
   hot path; :func:`~repro.obs.export.request_timelines` re-expands the
   batch events into one ordered per-request timeline (dispatch →
-  policy → admission spill → tier execute → complete).
+  policy → admission spill → tier execute → complete). Every span,
+  the disabled plane's included, is also a ``jax.profiler``
+  annotation ``repro.<name>``, so a profiler trace shows the host
+  stages beside the device operations; :func:`~repro.obs.trace.gc_spans`
+  adds the garbage collector's pauses (``repro.gc``) while it is
+  entered.
 * exporters — :func:`~repro.obs.export.to_jsonl` event log and
   :func:`~repro.obs.export.prometheus_text` metrics snapshot, both
   byte-deterministic under a :class:`~repro.obs.clock.ManualClock`
   (golden-tested).
-
-Profiling hooks for jitted device programs
-(:func:`~repro.obs.profile.profile_program`: ``block_until_ready``
-wall timing + HLO cost stats) live in :mod:`repro.obs.profile` and
-feed ``benchmarks/roofline_report.py`` measured — not just modeled —
-numbers.
 
 Observability is RUNTIME configuration, like ``runners=``: it is
 passed to ``repro.api.build(spec, obs=...)``, never serialized into
@@ -44,7 +43,7 @@ from repro.obs.registry import (  # noqa: F401
     MetricsRegistry,
     NullMetricsRegistry,
 )
-from repro.obs.trace import NullTracer, Span, Tracer  # noqa: F401
+from repro.obs.trace import NullTracer, Span, Tracer, gc_spans  # noqa: F401
 from repro.obs.plane import NULL_OBS, Observability  # noqa: F401
 from repro.obs.export import (  # noqa: F401
     prometheus_text,
@@ -52,15 +51,13 @@ from repro.obs.export import (  # noqa: F401
     span_tree,
     to_jsonl,
 )
-from repro.obs.profile import DeviceProgramProfile, profile_program  # noqa: F401
 
 __all__ = [
     "Observability", "NULL_OBS",
     "MetricsRegistry", "NullMetricsRegistry",
     "Counter", "Gauge", "Histogram", "DEFAULT_TIME_BUCKETS",
-    "Tracer", "NullTracer", "Span",
+    "Tracer", "NullTracer", "Span", "gc_spans",
     "Clock", "ManualClock", "MonotonicClock",
     "to_jsonl", "prometheus_text", "request_timelines", "span_tree",
-    "profile_program", "DeviceProgramProfile",
     "str_keyed", "int_keyed",
 ]

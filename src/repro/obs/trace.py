@@ -17,20 +17,32 @@ byte-deterministic. The event buffer is bounded (``max_events``,
 default 200k); overflow drops NEW events and counts them in
 ``n_dropped`` — a trace with holes is reported, never silently grown
 without bound.
+
+Every span, of the enabled and the disabled tracer alike, is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` for its length.
+With no profiler running the annotation records nothing; with one
+running, the spans land in the profiler's trace on the same clock as the
+device operations. :func:`gc_spans` puts the garbage collector's pauses
+there too (``repro.gc``).
 """
 
 from __future__ import annotations
 
+import contextlib
+import gc
 import threading
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.obs.clock import Clock, MonotonicClock
 
-__all__ = ["Span", "Tracer", "NullTracer", "NULL_SPAN"]
+__all__ = ["Span", "Tracer", "NullTracer", "gc_spans"]
 
 DEFAULT_MAX_EVENTS = 200_000
+#: a span named ``x`` shows in the profiler's trace as ``repro.x``
+_PREFIX = "repro."
 
 
 def _jsonable(v):
@@ -57,7 +69,8 @@ class Span:
             sp.event("spill", request_ids=[...])
     """
 
-    __slots__ = ("tracer", "trace_id", "span_id", "parent_id", "name")
+    __slots__ = ("tracer", "trace_id", "span_id", "parent_id", "name",
+                 "annotation")
 
     def __init__(self, tracer: "Tracer", trace_id: int, span_id: int,
                  parent_id: Optional[int], name: str):
@@ -66,35 +79,28 @@ class Span:
         self.span_id = span_id
         self.parent_id = parent_id
         self.name = name
+        self.annotation = TraceAnnotation(_PREFIX + name)
 
     def event(self, name: str, **attrs) -> None:
         self.tracer._record("event", self.trace_id, self.span_id, name, attrs)
 
     def __enter__(self) -> "Span":
+        self.annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.tracer._end(self)
+        self.annotation.__exit__(exc_type, exc, tb)
 
 
-class _NullSpan:
-    """Shared no-op span handed out by the disabled tracer."""
+class _NullSpan(TraceAnnotation):
+    """The disabled tracer's span: the profiler annotation alone (one
+    native object, no event log)."""
 
     __slots__ = ()
-    trace_id = span_id = parent_id = 0
-    name = ""
 
     def event(self, name: str, **attrs) -> None:
         pass
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        pass
-
-
-NULL_SPAN = _NullSpan()
 
 
 class Tracer:
@@ -192,13 +198,14 @@ class Tracer:
 
 
 class NullTracer:
-    """Disabled tracer: spans are the shared no-op span, events vanish."""
+    """Disabled tracer: spans are profiler annotations only, events
+    vanish."""
 
     enabled = False
     n_dropped = 0
 
     def span(self, name: str, **attrs) -> _NullSpan:
-        return NULL_SPAN
+        return _NullSpan(_PREFIX + name)
 
     def event(self, name: str, **attrs) -> None:
         pass
@@ -214,3 +221,29 @@ class NullTracer:
 
     def clear(self) -> None:
         pass
+
+
+@contextlib.contextmanager
+def gc_spans():
+    """Within the block, every garbage collection is a ``repro.gc``
+    profiler annotation from its start to its stop, with its generation
+    as metadata. Opt in while profiling: the hook runs on every
+    collection, young ones included. On exit the hook is removed."""
+    running: list[TraceAnnotation] = []
+
+    def hook(phase: str, info: dict) -> None:
+        if phase == "start":
+            ann = TraceAnnotation(_PREFIX + "gc",
+                                  generation=info["generation"])
+            ann.__enter__()
+            running.append(ann)
+        elif running:
+            running.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(hook)
+    try:
+        yield
+    finally:
+        gc.callbacks.remove(hook)
+        while running:
+            running.pop().__exit__(None, None, None)

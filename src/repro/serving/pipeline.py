@@ -177,7 +177,8 @@ class ServingPipeline:
             q = self._queued_ids[tier]
             rids, self._queued_ids[tier] = q[:len(batch)], q[len(batch):]
             t0 = self.obs.clock.now()
-        result = self.runners[tier](batch)
+        with self.obs.tracer.span("execute", tier=tier):
+            result = self.runners[tier](batch)
         self.executed.append(ExecutedBatch(tier=tier, size=len(batch),
                                            result=result))
         self.telemetry.n_microbatches += 1
@@ -241,25 +242,27 @@ class ServingPipeline:
                         request_ids=[res.first_id + int(i) for i in moved],
                         **{"from": res.tiers[moved].tolist(),
                            "to": exec_tiers[moved].tolist()})
-            # per-request records are lazy; only build them when they ARE
-            # the payloads — with explicit payloads the tier array is all
-            # we need
-            items = payloads if payloads is not None else res.records
-            self.telemetry.n_submitted += len(items)
-            self._m_submitted.inc(len(items))
-            if res.recalibrated:
-                self.telemetry.n_recalibrations += 1
-                self._m_recal.inc()
-            for i, (tier, item) in enumerate(zip(exec_tiers.tolist(), items)):
-                self.telemetry.tier_counts[tier] += 1
-                self._m_tiers[tier].inc()
+            with self.obs.tracer.span("handoff"):
+                # per-request records are lazy; only build them when they
+                # ARE the payloads — with explicit payloads the tier array
+                # is all we need
+                items = payloads if payloads is not None else res.records
+                self.telemetry.n_submitted += len(items)
+                self._m_submitted.inc(len(items))
+                if res.recalibrated:
+                    self.telemetry.n_recalibrations += 1
+                    self._m_recal.inc()
+                for i, (tier, item) in enumerate(zip(exec_tiers.tolist(),
+                                                     items)):
+                    self.telemetry.tier_counts[tier] += 1
+                    self._m_tiers[tier].inc()
+                    if obs_on:
+                        self._queued_ids[tier].append(res.first_id + i)
+                    for full in self.queues[tier].push(item):
+                        self._run(tier, full)
                 if obs_on:
-                    self._queued_ids[tier].append(res.first_id + i)
-                for full in self.queues[tier].push(item):
-                    self._run(tier, full)
-            if obs_on:
-                for tier, g in enumerate(self._g_pending):
-                    g.set(len(self.queues[tier]))
+                    for tier, g in enumerate(self._g_pending):
+                        g.set(len(self.queues[tier]))
         return res
 
     def flush(self) -> int:

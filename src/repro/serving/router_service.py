@@ -278,33 +278,37 @@ class SkewRouteDispatcher:
         ``self_scores``: optional [B] engine self-uncertainty (higher =
         less confident) some policies (cascade) fold into the decision.
         """
+        tracer = self.obs.tracer
         scores = np.asarray(scores_desc)
         b, k = scores.shape
-        bpad = bucket_size(b, BATCH_BUCKETS)
-        if bpad != b:
-            scores = np.concatenate(
-                [scores, np.zeros((bpad - b, k), scores.dtype)])
-        # always pass a concrete n_valid so every bucket shape compiles
-        # the kernel exactly once (None vs array would be two traces)
-        nv = np.full(bpad, k, np.int32)
-        if n_valid is not None:
-            nv[:b] = np.asarray(n_valid, np.int32)
-        nv[b:] = 1  # padded rows: degenerate but well-defined
-        with self.obs.tracer.span("dispatch", batch=b):
-            obs_on = self.obs.enabled
+        obs_on = self.obs.enabled
+        with tracer.span("dispatch", batch=b):
             t0 = self.obs.clock.now() if obs_on else 0.0
-            result: RouteBatchResult = self.backend.route_batch(
-                jnp.asarray(scores), self.router, n_valid=jnp.asarray(nv))
-            tiers = np.asarray(result.tiers)[:b]
-            diff = np.asarray(result.difficulty)[:b]
-            metrics = np.asarray(result.metrics)[:b]
+            with tracer.span("launch"):
+                bpad = bucket_size(b, BATCH_BUCKETS)
+                if bpad != b:
+                    scores = np.concatenate(
+                        [scores, np.zeros((bpad - b, k), scores.dtype)])
+                # always pass a concrete n_valid so every bucket shape
+                # compiles the kernel exactly once (None vs array would be
+                # two traces)
+                nv = np.full(bpad, k, np.int32)
+                if n_valid is not None:
+                    nv[:b] = np.asarray(n_valid, np.int32)
+                nv[b:] = 1  # padded rows: degenerate but well-defined
+                result: RouteBatchResult = self.backend.route_batch(
+                    jnp.asarray(scores), self.router, n_valid=jnp.asarray(nv))
+            with tracer.span("pull"):
+                tiers = np.asarray(result.tiers)[:b]
+                diff = np.asarray(result.difficulty)[:b]
+                metrics = np.asarray(result.metrics)[:b]
             if obs_on:  # np.asarray forced the device sync above
                 self._m_dispatch_s.observe(self.obs.clock.now() - t0)
-
-            decision = self.policy.decide(tiers, diff, metrics,
-                                          self_scores=self_scores)
-            first_id, metric_name, recalibrated = self._record_batch(
-                decision.tiers, diff, decision, backend_tiers=tiers)
+            with tracer.span("decide"):
+                decision = self.policy.decide(tiers, diff, metrics,
+                                              self_scores=self_scores)
+                first_id, metric_name, recalibrated = self._record_batch(
+                    decision.tiers, diff, decision, backend_tiers=tiers)
         if not return_details:
             return decision.tiers
         return BatchDispatchResult(tiers=decision.tiers, difficulty=diff,
@@ -327,41 +331,48 @@ class SkewRouteDispatcher:
         ``feats``: [B, N, Dt]; ``query_emb``: [B, Dq]; ``n_cand``:
         optional [B] real candidate counts (ragged retrieval).
         """
-        feats = np.asarray(feats)
-        b, k_feats, _ = feats.shape
-        bpad = bucket_size(b, BATCH_BUCKETS)
-        qemb = np.asarray(query_emb)
-        nc = np.full(bpad, k_feats, np.int32)
-        if n_cand is not None:
-            nc[:b] = np.asarray(n_cand, np.int32)
-        nc[b:] = 1  # padded rows: degenerate but well-defined
         if not hasattr(self.backend, "route_retrieved"):
             raise TypeError(
                 f"difficulty backend {self.backend.name!r} has no "
                 f"route_retrieved; end-to-end dispatch needs one of the "
                 f"built-in backends (oracle | pallas | fused | auto) or a "
                 f"custom backend implementing it")
-        if bpad != b:
-            feats = np.concatenate(
-                [feats, np.zeros((bpad - b,) + feats.shape[1:], feats.dtype)])
-            qemb = np.concatenate(
-                [qemb, np.zeros((bpad - b, qemb.shape[1]), qemb.dtype)])
-        with self.obs.tracer.span("dispatch_retrieved", batch=b):
-            obs_on = self.obs.enabled
+        tracer = self.obs.tracer
+        feats = np.asarray(feats)
+        b, k_feats, _ = feats.shape
+        obs_on = self.obs.enabled
+        with tracer.span("dispatch_retrieved", batch=b):
             t0 = self.obs.clock.now() if obs_on else 0.0
-            res = self.backend.route_retrieved(
-                jnp.asarray(feats), jnp.asarray(qemb), scorer_params,
-                self.router, n_cand=jnp.asarray(nc))
-            tiers = np.asarray(res.tiers)[:b]
-            diff = np.asarray(res.difficulty)[:b]
-            metrics = np.asarray(res.metrics)[:b]
+            with tracer.span("launch"):
+                bpad = bucket_size(b, BATCH_BUCKETS)
+                qemb = np.asarray(query_emb)
+                nc = np.full(bpad, k_feats, np.int32)
+                if n_cand is not None:
+                    nc[:b] = np.asarray(n_cand, np.int32)
+                nc[b:] = 1  # padded rows: degenerate but well-defined
+                if bpad != b:
+                    feats = np.concatenate(
+                        [feats, np.zeros((bpad - b,) + feats.shape[1:],
+                                         feats.dtype)])
+                    qemb = np.concatenate(
+                        [qemb, np.zeros((bpad - b, qemb.shape[1]),
+                                        qemb.dtype)])
+                res = self.backend.route_retrieved(
+                    jnp.asarray(feats), jnp.asarray(qemb), scorer_params,
+                    self.router, n_cand=jnp.asarray(nc))
+            with tracer.span("pull"):
+                tiers = np.asarray(res.tiers)[:b]
+                diff = np.asarray(res.difficulty)[:b]
+                metrics = np.asarray(res.metrics)[:b]
+                nv_out = np.asarray(res.n_valid)[:b]
+                probs = np.asarray(res.probs)[:b]
+                indices = np.asarray(res.indices)[:b]
             if obs_on:
                 self._m_dispatch_s.observe(self.obs.clock.now() - t0)
-            decision = self.policy.decide(tiers, diff, metrics)
-            first_id, metric_name, recalibrated = self._record_batch(
-                decision.tiers, diff, decision, backend_tiers=tiers)
-        nv_out = np.asarray(res.n_valid)[:b]
-        probs = np.asarray(res.probs)[:b]
+            with tracer.span("decide"):
+                decision = self.policy.decide(tiers, diff, metrics)
+                first_id, metric_name, recalibrated = self._record_batch(
+                    decision.tiers, diff, decision, backend_tiers=tiers)
         if decision.depths is not None:
             # Depth-routing: the candidate set each request SHIPS is the
             # routed depth — shrink the valid prefix and zero the probs
@@ -377,7 +388,7 @@ class SkewRouteDispatcher:
                 metric=metric_name, recalibrated=recalibrated,
                 request_cost=decision.request_cost,
                 depths=decision.depths),
-            indices=np.asarray(res.indices)[:b],
+            indices=indices,
             probs=probs,
             n_valid=nv_out)
 

@@ -1,9 +1,11 @@
-"""Unified observability plane (ISSUE tentpole): metrics registry,
-request tracing, exporters, device-program profiling — and the
-acceptance criterion: ONE canonical LoadRunner replay yields a complete
-per-request timeline (dispatch -> policy -> [spill] -> execute ->
-complete) for EVERY request, verified by walking the JSONL export."""
+"""Unified observability plane: metrics registry, request tracing,
+exporters, spans as profiler annotations — and ONE canonical LoadRunner
+replay yields a complete per-request timeline (dispatch -> policy ->
+[spill] -> execute -> complete) for EVERY request, verified by walking
+the JSONL export."""
 
+import gc
+import glob
 import json
 
 import numpy as np
@@ -11,9 +13,9 @@ import pytest
 
 from repro.api import CalibrationSpec, RouteSpec, build
 from repro.obs import (NULL_OBS, DEFAULT_TIME_BUCKETS, ManualClock,
-                       MetricsRegistry, Observability, int_keyed,
-                       prometheus_text, profile_program,
-                       request_timelines, span_tree, str_keyed, to_jsonl)
+                       MetricsRegistry, Observability, gc_spans, int_keyed,
+                       prometheus_text, request_timelines, span_tree,
+                       str_keyed, to_jsonl)
 from repro.serving.loadgen import canonical_load_runner, canonical_trace
 
 
@@ -305,22 +307,126 @@ def test_trace_events_never_serialize():
                for s in state["samples"])
 
 
-# -- device-program profiling -------------------------------------------------
+# -- spans on the profiler's clock ---------------------------------------------
 
-def test_profile_program_measures_and_registers():
+def _host_events(trace_dir) -> list[tuple]:
+    """``(name, start_ns, end_ns, stats)`` of the ``repro.*`` and
+    ``bench.*`` events on the host planes of the one trace under
+    ``trace_dir``, outer before inner."""
+    import jax
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("repro.", "bench.")):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return sorted(out, key=lambda x: (x[1], -x[2]))
+
+
+def _forest(events) -> list:
+    """Nest the events by containment: ``[name, children]`` roots."""
+    roots, stack = [], []
+    for name, s, e, _ in events:
+        node = (name, s, e, [])
+        while stack and not (stack[-1][1] <= s and e <= stack[-1][2]):
+            stack.pop()
+        (stack[-1][3] if stack else roots).append(node)
+        stack.append(node)
+
+    def strip(node):
+        return [node[0], [strip(c) for c in node[3]]]
+    return [strip(r) for r in roots]
+
+
+def _served_path_calls(obs, trace_dir):
+    """Three calls, each in a ``bench.call`` annotation: submit on each
+    side of auto's crossover, and end-to-end retrieve dispatch. Returns
+    the trace's forest and the micro-batches each submit executed."""
+    import jax
     import jax.numpy as jnp
+    from jax.profiler import TraceAnnotation
 
-    reg = MetricsRegistry()
-    prof = profile_program(lambda x: jnp.sum(x * 2.0),
-                           (jnp.ones((64, 32), jnp.float32),),
-                           name="toy", shape="64x32", iters=2, warmup=1,
-                           registry=reg)
-    assert prof.wall_s > 0 and prof.compile_s > 0
-    assert prof.flops >= 0 and prof.achieved_gflops >= 0
-    assert reg.value("program_wall_seconds", program="toy",
-                     shape="64x32") == prof.wall_s
-    d = prof.to_dict()
-    assert d["name"] == "toy" and json.loads(json.dumps(d)) == d
+    rng = np.random.default_rng(11)
+    session = build(mk_spec(crossover_batch=32, micro_batch=8),
+                    runners={0: lambda b: b, 1: lambda b: b}, obs=obs)
+    params = {k: jnp.asarray(rng.normal(0, 0.3, s).astype(np.float32))
+              for k, s in (("w1_t", (12, 16)), ("w1_q", (8, 16)),
+                           ("b1", (16,)), ("w2", (16, 1)), ("b2", (1,)))}
+    feats = rng.normal(0, 1, (3, 64, 12)).astype(np.float32)
+    qemb = rng.normal(0, 1, (3, 8)).astype(np.float32)
+    calls = [lambda: session.submit(desc_scores(rng, 5)),
+             lambda: session.submit(desc_scores(rng, 40)),
+             lambda: session.route_retrieved(feats, qemb, params)]
+    for call in calls:               # compile outside the trace
+        call()
+    ran = []
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        for call in calls:
+            before = session.pipeline.telemetry.n_microbatches
+            with TraceAnnotation("bench.call"):
+                call()
+            ran.append(session.pipeline.telemetry.n_microbatches - before)
+    finally:
+        jax.profiler.stop_trace()
+    return _forest(_host_events(trace_dir)), ran
+
+
+@pytest.mark.parametrize("plane", ["null", "enabled"])
+def test_served_path_spans_land_in_the_profiler_trace(plane, tmp_path):
+    obs = Observability(clock=ManualClock()) if plane == "enabled" else None
+    forest, ran = _served_path_calls(obs, tmp_path)
+    assert [r[0] for r in forest] == ["bench.call"] * 3
+    stages = [["repro.launch", []], ["repro.pull", []],
+              ["repro.decide", []]]
+    for (_, call), n_exec in zip(forest[:2], ran[:2]):
+        # one submit a call: dispatch, then the hand-off with one execute
+        # span per micro-batch the call filled
+        assert call == [["repro.submit", [
+            ["repro.dispatch", stages],
+            ["repro.handoff", [["repro.execute", []]] * n_exec]]]]
+    assert ran[1] >= 4                # 40 rows fill at least 4 of 8
+    assert forest[2][1] == [["repro.dispatch_retrieved", stages]]
+
+
+def test_null_plane_records_no_events_on_the_served_path():
+    session = build(mk_spec(), runners={0: lambda b: b, 1: lambda b: b})
+    rng = np.random.default_rng(12)
+    for b in (5, 40):
+        session.submit(desc_scores(rng, b))
+    session.flush()
+    session.route(desc_scores(rng, 8))
+    assert session.obs is NULL_OBS
+    assert NULL_OBS.tracer.events() == [] and len(NULL_OBS.tracer) == 0
+    assert NULL_OBS.metrics.state_dict() == {"samples": []}
+
+
+def test_gc_spans_annotate_collections_and_restore_callbacks(tmp_path):
+    import jax
+    before = list(gc.callbacks)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with gc_spans():
+            assert len(gc.callbacks) == len(before) + 1
+            gc.collect()
+        gc.collect()                  # after the block: no span
+    finally:
+        jax.profiler.stop_trace()
+    assert gc.callbacks == before
+    # young collections may come and go; the one full collection inside
+    # the block is there, the one after it is not
+    spans = [e for e in _host_events(tmp_path) if e[0] == "repro.gc"]
+    full = [e for e in spans if e[3] == {"generation": 2}]
+    assert len(full) == 1 and full[0][2] > full[0][1]
+    with pytest.raises(RuntimeError):
+        with gc_spans():
+            raise RuntimeError("the hook goes on the way out too")
+    assert gc.callbacks == before
 
 
 # -- mode topology (satellite: no_rag tiers skip retrieval-sized prompts) -----
@@ -390,13 +496,19 @@ def test_canonical_replay_yields_complete_timelines(tmp_path):
     # spill hops in the trace == the controller's spill counter
     assert len(spilled) == report.summary["n_spilled"] > 0
 
-    # span forest: every submit span contains a dispatch child
+    # span forest: every submit span contains a dispatch child, which
+    # holds the launch, pull and decide stages; the hand-off follows it
     tree = span_tree(events)
     submits = [s for s in tree.values() if s["name"] == "submit"]
     assert submits
     for s in submits:
         kids = {tree[c]["name"] for c in s["children"]}
         assert "dispatch" in kids
+        assert kids == {"dispatch", "handoff"}
+        dispatch = next(tree[c] for c in s["children"]
+                        if tree[c]["name"] == "dispatch")
+        assert [tree[c]["name"] for c in dispatch["children"]] == [
+            "launch", "pull", "decide"]
 
     # the registry tells the same aggregate story as the telemetry
     t = runner.session.pipeline.telemetry

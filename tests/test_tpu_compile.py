@@ -12,6 +12,7 @@ process at a time may load the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +48,11 @@ def _compiled_text(fn, *shapes) -> str:
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _kernel_ops(text: str) -> list[str]:
+    """Names of the compiled program's kernel operations."""
+    return re.findall(r"%([\w-]+?)(?:\.\d+)? = [^\n]*tpu_custom_call", text)
+
+
 def _shape(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -67,6 +73,7 @@ def test_triple_score_batched_compiles(one_chip, b, n, dt, dq, h):
         _shape(one_chip, (b, n, dt)), _shape(one_chip, (b, dq)),
         *_scorer_shapes(one_chip, dt, dq, h))
     assert "tpu_custom_call" in text
+    assert _kernel_ops(text) == ["triple_score_batched"]
 
 
 def test_triple_score_shared_candidates_compiles(one_chip):
@@ -75,6 +82,7 @@ def test_triple_score_shared_candidates_compiles(one_chip):
         _shape(one_chip, (512, 110)), _shape(one_chip, (3, 32)),
         *_scorer_shapes(one_chip, 110, 32, 128))
     assert "tpu_custom_call" in text
+    assert _kernel_ops(text) == ["triple_score"]
 
 
 @pytest.mark.parametrize("b", [8, 1024])
@@ -83,6 +91,7 @@ def test_skew_metrics_ragged_compiles(one_chip, b):
         lambda s, nv: skew_kernel.skew_metrics(s, nv, interpret=False),
         _shape(one_chip, (b, 100)), _shape(one_chip, (b,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _kernel_ops(text) == ["skew_metrics"]
 
 
 def test_fused_retrieve_to_decision_compiles(one_chip):
@@ -97,3 +106,5 @@ def test_fused_retrieve_to_decision_compiles(one_chip):
         *_scorer_shapes(one_chip, 110, 32, 128), _shape(one_chip, (1,)),
         _shape(one_chip, (64,), jnp.int32))
     assert text.count("tpu_custom_call") >= 2  # scoring + skew kernels
+    assert sorted(_kernel_ops(text)) == ["skew_metrics",
+                                         "triple_score_batched"]
