@@ -54,6 +54,7 @@ from typing import Callable, Mapping, Optional, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.router import (RetrievedRouteResult, RouteBatchResult,
                                RouterConfig, route_all_metrics,
@@ -65,6 +66,15 @@ from repro.kernels.device import default_interpret  # noqa: F401  (re-export)
 # (0.25–0.72x at B=1) and wins decisively from B=64 up (18–79x). On TPU
 # the compiled kernel wins earlier — deployments set the spec field.
 DEFAULT_CROSSOVER_BATCH = 32
+
+
+def _as_rows(scores_desc) -> jax.Array:
+    """Score rows as a ``[B, K]`` array without a device program: a 2-D
+    array passes through as it is (a device array stays where it is),
+    and only a single ``[K]`` row is lifted to ``[1, K]``, on the host."""
+    if np.ndim(scores_desc) == 2:
+        return jnp.asarray(scores_desc)
+    return jnp.asarray(np.atleast_2d(np.asarray(scores_desc)))
 
 
 @runtime_checkable
@@ -115,7 +125,7 @@ class _SingleProgramBackend:
 
     def route_batch(self, scores_desc, config: RouterConfig, n_valid=None):
         return route_all_metrics(
-            jnp.atleast_2d(jnp.asarray(scores_desc)), config,
+            _as_rows(scores_desc), config,
             n_valid=None if n_valid is None else jnp.asarray(n_valid),
             interpret=self.effective_interpret(),
             use_kernel=self._use_kernel)
@@ -218,12 +228,12 @@ class AutoBackend:
         return side
 
     def metrics(self, scores_desc, p_cdf: float = 0.95, n_valid=None):
-        scores = jnp.atleast_2d(jnp.asarray(scores_desc))
+        scores = _as_rows(scores_desc)
         return self.pick(scores.shape[0]).metrics(scores, p_cdf=p_cdf,
                                                   n_valid=n_valid)
 
     def route_batch(self, scores_desc, config: RouterConfig, n_valid=None):
-        scores = jnp.atleast_2d(jnp.asarray(scores_desc))
+        scores = _as_rows(scores_desc)
         return self.pick(scores.shape[0]).route_batch(scores, config,
                                                       n_valid=n_valid)
 

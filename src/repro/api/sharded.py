@@ -175,11 +175,10 @@ class ShardedBackend:
                                    ragged, use_kernel, interpret, row, vec)
         thr = _thresholds_array(config.thresholds)
         if ragged:
-            tiers, diff, metrics = prog(scores, jnp.asarray(nv), thr)
+            decision = prog(scores, jnp.asarray(nv), thr)
         else:
-            tiers, diff, metrics = prog(scores, thr)
-        return RouteBatchResult(tiers=tiers[:b], difficulty=diff[:b],
-                                metrics=metrics[:b])
+            decision = prog(scores, thr)
+        return RouteBatchResult(decision=decision[:b], metric=config.metric)
 
     def route_retrieved(self, feats, query_emb, params: Mapping,
                         config: RouterConfig,
@@ -251,7 +250,7 @@ class ShardedBackend:
         in_specs = (row, vec, P()) if ragged else (row, P())
         prog = jax.jit(jax.shard_map(
             body_ragged if ragged else body_dense, mesh=self.mesh,
-            in_specs=in_specs, out_specs=(vec, vec, row), check_vma=False))
+            in_specs=in_specs, out_specs=row, check_vma=False))
         self._programs[key] = prog
         return prog
 

@@ -33,12 +33,13 @@ import functools
 import threading
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.calibrate import calibrate_multi_tier
 from repro.core.cost import CostModel
-from repro.core.router import RouteBatchResult, RouterConfig
+from repro.core.router import RouteBatchResult, RouterConfig, unpack_decision
 from repro.core.streaming_calibrate import StreamingCalibrator
 from repro.obs import NULL_OBS, str_keyed, int_keyed
 from repro.serving import _deprecation
@@ -186,6 +187,8 @@ class SkewRouteDispatcher:
         self._m_tiers = [m.counter("routing_tier_decisions_total",
                                    tier=str(t))
                          for t in range(router.n_tiers)]
+        self._m_h2d = m.counter("dispatch_transfers_total", direction="h2d")
+        self._m_d2h = m.counter("dispatch_transfers_total", direction="d2h")
 
     def _obs_resync(self) -> None:
         """Point the registry's dispatcher mirrors at the (restored)
@@ -256,6 +259,19 @@ class SkewRouteDispatcher:
 
     # -- dispatch -------------------------------------------------------------
 
+    def _put(self, *host_arrays) -> tuple:
+        """A dispatch's one host-to-device transfer: every input of the
+        device program in a single ``jax.device_put``."""
+        self._m_h2d.inc()
+        return jax.device_put(host_arrays)
+
+    def _pull(self, b: int, *device_arrays) -> list:
+        """A dispatch's one device-to-host read: ``jax.device_get`` starts
+        every copy before it blocks; the bucket padding is sliced off on
+        the host."""
+        self._m_d2h.inc()
+        return [a[:b] for a in jax.device_get(device_arrays)]
+
     def dispatch(self, scores_desc: np.ndarray,
                  n_valid: Optional[int] = None) -> DispatchRecord:
         """Route one request — same fused kernel, batch of one (bucketed
@@ -296,13 +312,13 @@ class SkewRouteDispatcher:
                 if n_valid is not None:
                     nv[:b] = np.asarray(n_valid, np.int32)
                 nv[b:] = 1  # padded rows: degenerate but well-defined
+                scores, nv = self._put(scores, nv)
                 result: RouteBatchResult = self.backend.route_batch(
-                    jnp.asarray(scores), self.router, n_valid=jnp.asarray(nv))
+                    scores, self.router, n_valid=nv)
             with tracer.span("pull"):
-                tiers = np.asarray(result.tiers)[:b]
-                diff = np.asarray(result.difficulty)[:b]
-                metrics = np.asarray(result.metrics)[:b]
-            if obs_on:  # np.asarray forced the device sync above
+                (buf,) = self._pull(b, result.decision)
+                tiers, diff, metrics = unpack_decision(buf, result.metric)
+            if obs_on:  # the pull forced the device sync above
                 self._m_dispatch_s.observe(self.obs.clock.now() - t0)
             with tracer.span("decide"):
                 decision = self.policy.decide(tiers, diff, metrics,
@@ -357,16 +373,13 @@ class SkewRouteDispatcher:
                     qemb = np.concatenate(
                         [qemb, np.zeros((bpad - b, qemb.shape[1]),
                                         qemb.dtype)])
+                feats, qemb, nc = self._put(feats, qemb, nc)
                 res = self.backend.route_retrieved(
-                    jnp.asarray(feats), jnp.asarray(qemb), scorer_params,
-                    self.router, n_cand=jnp.asarray(nc))
+                    feats, qemb, scorer_params, self.router, n_cand=nc)
             with tracer.span("pull"):
-                tiers = np.asarray(res.tiers)[:b]
-                diff = np.asarray(res.difficulty)[:b]
-                metrics = np.asarray(res.metrics)[:b]
-                nv_out = np.asarray(res.n_valid)[:b]
-                probs = np.asarray(res.probs)[:b]
-                indices = np.asarray(res.indices)[:b]
+                tiers, diff, metrics, nv_out, probs, indices = self._pull(
+                    b, res.tiers, res.difficulty, res.metrics, res.n_valid,
+                    res.probs, res.indices)
             if obs_on:
                 self._m_dispatch_s.observe(self.obs.clock.now() - t0)
             with tracer.span("decide"):

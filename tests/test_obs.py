@@ -255,6 +255,39 @@ def test_backend_pick_counter_tracks_crossover():
     assert obs.metrics.value("backend_pick_total", path="fused") == 1
 
 
+def _dispatch_calls(session, rng):
+    """A submit on each side of auto's crossover (buckets 8 and 64) and
+    one end-to-end retrieve dispatch."""
+    import jax.numpy as jnp
+    params = {k: jnp.asarray(rng.normal(0, 0.3, s).astype(np.float32))
+              for k, s in (("w1_t", (12, 16)), ("w1_q", (8, 16)),
+                           ("b1", (16,)), ("w2", (16, 1)), ("b2", (1,)))}
+    feats = rng.normal(0, 1, (3, 64, 12)).astype(np.float32)
+    qemb = rng.normal(0, 1, (3, 8)).astype(np.float32)
+    return [lambda: session.submit(desc_scores(rng, 5)),
+            lambda: session.submit(desc_scores(rng, 40)),
+            lambda: session.route_retrieved(feats, qemb, params)]
+
+
+@pytest.mark.parametrize("plane", ["null", "enabled"])
+def test_dispatch_transfers_counter_reads_one_each_way_per_dispatch(plane):
+    obs = Observability(clock=ManualClock()) if plane == "enabled" else None
+    session = build(mk_spec(crossover_batch=32, micro_batch=8),
+                    runners={0: lambda b: b, 1: lambda b: b}, obs=obs)
+    for n, call in enumerate(_dispatch_calls(session,
+                                             np.random.default_rng(13)), 1):
+        call()
+        if obs is None:
+            assert NULL_OBS.metrics.state_dict() == {"samples": []}
+            continue
+        for direction in ("h2d", "d2h"):
+            assert obs.metrics.value("dispatch_transfers_total",
+                                     direction=direction) == n
+    if obs is not None:
+        assert obs.metrics.value("backend_pick_total", path="oracle") == 2
+        assert obs.metrics.value("backend_pick_total", path="fused") == 1
+
+
 # -- snapshot / restore -------------------------------------------------------
 
 def test_obs_state_rides_the_envelope_and_restores():
@@ -348,20 +381,11 @@ def _served_path_calls(obs, trace_dir):
     side of auto's crossover, and end-to-end retrieve dispatch. Returns
     the trace's forest and the micro-batches each submit executed."""
     import jax
-    import jax.numpy as jnp
     from jax.profiler import TraceAnnotation
 
-    rng = np.random.default_rng(11)
     session = build(mk_spec(crossover_batch=32, micro_batch=8),
                     runners={0: lambda b: b, 1: lambda b: b}, obs=obs)
-    params = {k: jnp.asarray(rng.normal(0, 0.3, s).astype(np.float32))
-              for k, s in (("w1_t", (12, 16)), ("w1_q", (8, 16)),
-                           ("b1", (16,)), ("w2", (16, 1)), ("b2", (1,)))}
-    feats = rng.normal(0, 1, (3, 64, 12)).astype(np.float32)
-    qemb = rng.normal(0, 1, (3, 8)).astype(np.float32)
-    calls = [lambda: session.submit(desc_scores(rng, 5)),
-             lambda: session.submit(desc_scores(rng, 40)),
-             lambda: session.route_retrieved(feats, qemb, params)]
+    calls = _dispatch_calls(session, np.random.default_rng(11))
     for call in calls:               # compile outside the trace
         call()
     ran = []
