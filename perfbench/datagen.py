@@ -7,9 +7,10 @@ Copied from the program's generators, with the changes each docstring names:
   (pre-scored top-K rows), plus a ragged share of rows with fewer than K
   valid scores.
 * ``make_kg``: `repro.retrieval.synthetic.make_kg`, returning the
-  reference's `Graph` of the triples. The tail search runs at the narrow structural width; the wide
-  entity and relation tables are projected from the narrow ones, so that
-  a 1024-wide deployment never builds the ``[E, 16, d]`` search array.
+  reference's `Graph` of the triples. The tail search runs at the narrow
+  structural width, in chunks of edges on threads; the wide entity and
+  relation tables are projected from the narrow ones, so that a 1024-wide
+  deployment never builds the ``[E, 16, d]`` search array.
 * ``make_queries``: `repro.retrieval.synthetic.make_queries` over that
   graph.
 """
@@ -17,10 +18,15 @@ Copied from the program's generators, with the changes each docstring names:
 from __future__ import annotations
 
 import dataclasses
+import os
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
 from perfbench.reference import Graph
+
+#: edges per step of `make_kg`'s tail search
+CHUNK = 4096
 
 HOP_MIX = {
     "webqsp": {1: 0.655, 2: 0.345},
@@ -71,6 +77,12 @@ def make_kg(n_entities: int, n_relations: int, avg_degree: float,
     Returns the graph and ``width``-wide entity and relation tables: the
     narrow embeddings through one random projection, so the compositional
     structure holds at the wide width too.
+
+    The draws come in the original order: the candidate tails whole, then
+    the noise ``CHUNK`` edges at a time, each chunk's float64 search
+    (``norm(ent[pool] - target)``, argmin over the 16 candidates) running
+    on a thread while the next chunk is drawn. The search never holds the
+    ``[n_edges, 16, structure_dim]`` array.
     """
     rng = np.random.default_rng(seed)
     ent = rng.normal(0, 1, (n_entities, structure_dim)).astype(np.float32)
@@ -81,10 +93,24 @@ def make_kg(n_entities: int, n_relations: int, avg_degree: float,
     heads = np.repeat(np.arange(n_entities, dtype=np.int32), deg)
     rels = rng.integers(0, n_relations, n_edges).astype(np.int32)
     pool = rng.integers(0, n_entities, (n_edges, 16))
-    target = ent[heads] + rel[rels] + rng.normal(0, 0.3,
-                                                 (n_edges, structure_dim))
-    dists = np.linalg.norm(ent[pool] - target[:, None, :], axis=-1)
-    tails = pool[np.arange(n_edges), dists.argmin(1)].astype(np.int32)
+    ent64 = ent.astype(np.float64)
+    tails = np.empty(n_edges, np.int32)
+
+    def search(s: int, noise: np.ndarray) -> None:
+        e = s + len(noise)
+        target = ent[heads[s:e]] + rel[rels[s:e]] + noise
+        d = ent64[pool[s:e]]
+        d -= target[:, None, :]
+        d *= d
+        dists = np.sqrt(np.add.reduce(d, axis=-1))
+        tails[s:e] = pool[np.arange(s, e), dists.argmin(1)]
+
+    with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as ex:
+        for f in [ex.submit(search, s, rng.normal(
+                      0, 0.3, (min(CHUNK, n_edges - s), structure_dim)))
+                  for s in range(0, n_edges, CHUNK)]:
+            f.result()
+    del pool, ent64
     proj = rng.normal(0, structure_dim ** -0.5,
                       (structure_dim, width)).astype(np.float32)
     return (Graph(heads, rels, tails, n_entities, n_relations),

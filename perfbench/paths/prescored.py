@@ -8,12 +8,31 @@ sample, to the configured tier shares, and stay static in the window.
 
 from __future__ import annotations
 
+import copy
+
 import numpy as np
 
 from perfbench import compare, datagen, reference, traffic
 
 
+def small(config: dict, mix: dict) -> tuple[dict, dict]:
+    """Copies of a configuration and mix cut for the CPU tests: a pool of
+    2,048 rows and, in an open loop, 1,000 arrivals a second; K and the
+    batches as run."""
+    config, mix = copy.deepcopy((config, mix))
+    mix["pool"] = 2048
+    if mix["loop"] == "open":
+        mix["rate"] = 1000
+    return config, mix
+
+
 class Deployment:
+    #: where each entry of ``outputs`` holds the served tiers
+    tiers_column = 1
+    #: whether requests reach tier runners through the program's pipeline:
+    #: ``_handoff(tier)`` builds each runner, ``session.pipeline`` queues
+    hands_off = True
+
     def __init__(self, config: dict, mix: dict, seed: int, spans):
         self.config, self.mix, self.seed, self.spans = config, mix, seed, spans
         self.k = int(config["top_k"])

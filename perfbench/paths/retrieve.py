@@ -13,6 +13,8 @@ sample and the sample that is checked.
 
 from __future__ import annotations
 
+import copy
+
 import numpy as np
 
 from perfbench import compare, datagen, reference, traffic
@@ -56,7 +58,26 @@ def dot_high(a, b):
     return dot(ah, bh) + dot(ah, bl) + dot(al, bh)
 
 
+def small(config: dict, mix: dict) -> tuple[dict, dict]:
+    """Copies of a configuration and mix cut for the CPU tests: 64-wide
+    embeddings and hidden layer over 2,000 entities, 8 calibration and 12
+    checked questions, a pool of 32 and, in an open loop, 10 arrivals a
+    second."""
+    config, mix = copy.deepcopy((config, mix))
+    config.update(d_emb=64, d_hidden=64, n_entities=2000,
+                  calibration_questions=8, check_requests=12)
+    mix["pool"] = 32
+    if mix["loop"] == "open":
+        mix["rate"] = 10
+    return config, mix
+
+
 class Deployment:
+    #: where each entry of ``outputs`` holds the served tiers
+    tiers_column = 5
+    #: ``route_retrieved`` returns decisions and hands nothing to tiers
+    hands_off = False
+
     def __init__(self, config: dict, mix: dict, seed: int, spans):
         self.config, self.mix, self.seed, self.spans = config, mix, seed, spans
         self.k = int(config["top_k"])

@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 from perfbench import compare, harness, tracing
-from perfbench.tests.cells import MIXES, small_cell
+from perfbench.tests.cells import (MIXES, cut, hands_off, load,
+                                   register_kind, small_cell)
 
 #: one mix of each configuration: the control replaces the program
 CONTROL_MIXES = sorted({config: (config, traffic)
@@ -32,16 +33,12 @@ def test_control_is_not_correct(config, traffic):
     assert not control_ok, control
 
 
-def _tier_column(dep) -> int:
-    return 1 if "pool_nv" in vars(dep) else 5
-
-
 def alter_one_answer(dep, serve, i, j):
     """Each call's first decision is flipped to the other tier where it
     is produced."""
     serve(i, j)
     out = list(dep.outputs[-1])
-    col = _tier_column(dep)
+    col = dep.tiers_column
     tiers = np.array(out[col])
     tiers[0] = 1 - tiers[0]
     out[col] = tiers
@@ -54,20 +51,27 @@ def leave_out_half(dep, serve, i, j):
         serve(i, i + (j - i) // 2)
 
 
+def _run_broken(cell, fault) -> dict:
+    """A CPU run of ``cell`` with ``fault`` planted under its timed path:
+    a class that overrides the deployment's methods, or a function that
+    wraps each call's ``serve``."""
+    sound = harness.deployment_class(cell.config)
+    if isinstance(fault, type):
+        broken = type("Broken", (fault, sound), {})
+    else:
+        class broken(sound):
+            def serve(self, i, j):
+                fault(self, super().serve, i, j)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(harness, "deployment_class", lambda config: broken)
+        return harness.run(cell.name, SEED, 1.0, False, time.perf_counter(),
+                           need_tpu=False, cell=cell)
+
+
 @pytest.mark.parametrize("fault", [alter_one_answer, leave_out_half])
 @pytest.mark.parametrize("config,traffic", MIXES)
-def test_broken_timed_path_is_not_correct(config, traffic, fault,
-                                          monkeypatch):
-    cell = small_cell(config, traffic)
-    sound = harness.deployment_class(cell.config)
-
-    class Broken(sound):
-        def serve(self, i, j):
-            fault(self, super().serve, i, j)
-
-    monkeypatch.setattr(harness, "deployment_class", lambda config: Broken)
-    res = harness.run(cell.name, SEED, 1.0, False, time.perf_counter(),
-                      need_tpu=False, cell=cell)
+def test_broken_timed_path_is_not_correct(config, traffic, fault):
+    res = _run_broken(small_cell(config, traffic), fault)
     assert not res["correct"], res["checks"]
 
 
@@ -88,16 +92,31 @@ class DroppedTail:
             q.flush()
 
 
-PRESCORED_MIXES = [m for m in MIXES if m[0].startswith("prescored")]
+#: the mixes of every kind that declares a hand-off to tier runners
+HANDOFF_MIXES = [m for m in MIXES if hands_off(load(*m)[0])]
 
 
 @pytest.mark.parametrize("fault", [SwappedRunners, DroppedTail])
-@pytest.mark.parametrize("config,traffic", PRESCORED_MIXES)
-def test_broken_handoff_is_not_correct(config, traffic, fault, monkeypatch):
-    cell = small_cell(config, traffic)
-    broken = type("Broken", (fault, harness.deployment_class(cell.config)),
-                  {})
-    monkeypatch.setattr(harness, "deployment_class", lambda config: broken)
-    res = harness.run(cell.name, SEED, 1.0, False, time.perf_counter(),
-                      need_tpu=False, cell=cell)
+@pytest.mark.parametrize("config,traffic", HANDOFF_MIXES)
+def test_broken_handoff_is_not_correct(config, traffic, fault):
+    res = _run_broken(small_cell(config, traffic), fault)
+    assert not res["correct"], res["checks"]
+
+
+@pytest.mark.parametrize("fault", [SwappedRunners, DroppedTail,
+                                   alter_one_answer])
+def test_unseen_kind_gets_the_faults_it_declares(fault, monkeypatch):
+    """A kind of deployment that no file holds, which hands requests to
+    tier runners as the pre-scored kind does, is picked for the hand-off
+    faults by its declaration, and the tier fault finds its tiers where it
+    declares them."""
+    def small(config, mix):
+        return dict(config), dict(mix, pool=1024, rate=400)
+
+    cfg, mix = register_kind(monkeypatch, "_unseen_handoff", small=small,
+                             hands_off=True, tiers_column=1)
+    assert hands_off(cfg)
+    cfg, mix = cut(cfg, mix)
+    res = _run_broken(harness.Cell("unseen.online", 1, cfg, mix, [], []),
+                      fault)
     assert not res["correct"], res["checks"]
