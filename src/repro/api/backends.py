@@ -17,7 +17,8 @@ kernel choice were re-derived ad hoc wherever dispatch happened
   device top-k -> fused skew kernel -> threshold decision, chained in
   one jitted computation (scores never leave HBM). Same scores-in
   contract as ``pallas`` for :meth:`~DifficultyBackend.route_batch`,
-  plus :meth:`route_retrieved` for candidate-features-in routing.
+  plus :meth:`route_retrieved` for candidate-features-in routing and
+  :meth:`route_kg` for questions over a knowledge graph on the device.
 * ``auto``   — the production policy: a measured BATCH-SIZE CROSSOVER.
   Batches below ``crossover_batch`` go to the ``oracle`` program (which
   wins at small B — the seed's kernel-everywhere policy LOST to the
@@ -57,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.router import (RetrievedRouteResult, RouteBatchResult,
-                               RouterConfig, route_all_metrics,
+                               RouterConfig, route_all_metrics, route_kg,
                                route_retrieved)
 from repro.kernels.device import default_interpret  # noqa: F401  (re-export)
 
@@ -150,6 +151,18 @@ class _SingleProgramBackend:
             interpret=interp,
             use_kernels=self._use_kernel and not interp)
 
+    def route_kg(self, store, params: Mapping, config: RouterConfig,
+                 topics, query_emb, cand_ids, n_cand) -> jax.Array:
+        """Questions -> features gathered from ``store`` (a
+        `repro.retrieval.store.DeviceKG`) -> scoring -> top-k -> decision,
+        one jitted program returning one packed buffer
+        (`repro.core.router.route_kg`); off-TPU on the XLA ops, as
+        :meth:`route_retrieved`."""
+        interp = self.effective_interpret()
+        return route_kg(store, params, config, topics, query_emb, cand_ids,
+                        n_cand, interpret=interp,
+                        use_kernels=self._use_kernel and not interp)
+
 
 class OracleBackend(_SingleProgramBackend):
     """XLA ground-truth backend (`core.skewness` metrics, stacked) — one
@@ -241,6 +254,11 @@ class AutoBackend:
                         config: RouterConfig, n_cand=None):
         return self.pick(jnp.asarray(feats).shape[0]).route_retrieved(
             feats, query_emb, params, config, n_cand=n_cand)
+
+    def route_kg(self, store, params: Mapping, config: RouterConfig,
+                 topics, query_emb, cand_ids, n_cand):
+        return self.pick(cand_ids.shape[0]).route_kg(
+            store, params, config, topics, query_emb, cand_ids, n_cand)
 
 
 # --- registry ----------------------------------------------------------------

@@ -8,6 +8,11 @@ cost telemetry — behind three verbs:
 * ``session.route(scores)``          — batched tier assignment (fast path)
 * ``session.submit(scores, items)``  — route AND pump per-tier micro-
   batches through the tier runners (needs ``runners=`` at build time)
+* ``session.submit_questions(topics, query_emb, cand_ids, n_cand)`` —
+  questions over a knowledge graph held on the device: features, triple
+  scores, top-K, skew and tiers in one device program, then each
+  question and its top-K triple ids to its tier's runner (needs
+  ``runners=``, ``store=`` and ``scorer=``)
 * ``session.snapshot()/restore()``   — the complete mutable routing state
   (hot-swapped thresholds, calibrator window, telemetry counters) as a
   JSON-serializable dict, so multi-replica deployments can ship policy
@@ -28,6 +33,7 @@ import warnings
 from typing import (Callable, Mapping, Optional, Protocol, Sequence, Union,
                     runtime_checkable)
 
+import jax
 import numpy as np
 
 from repro.api import backends as _backends
@@ -56,14 +62,24 @@ class SkewRouteSession:
 
     def __init__(self, spec: RouteSpec,
                  runners: Optional[Union[Runners, EngineBankLike]] = None,
-                 obs: Optional[Observability] = None):
+                 obs: Optional[Observability] = None,
+                 store=None, scorer: Optional[Mapping] = None):
         self.spec = spec
+        # The knowledge graph on the device (a DeviceKG) and the triple
+        # scorer's weights, for submit_questions: runtime, like runners,
+        # never part of the spec or a snapshot. The weights go to the
+        # device once, here.
+        self.store = store
+        self.scorer = None if scorer is None else jax.device_put(
+            {k: np.asarray(v, np.float32) for k, v in scorer.items()})
         # Observability is RUNTIME configuration (like runners): an
         # `Observability` plane to record into, or None for the no-op
         # plane. Never serialized into the spec; metric VALUES ride the
         # snapshot envelope's state half when enabled (state["obs"]),
         # trace events never do (local measurement history).
         self.obs = obs or NULL_OBS
+        if store is not None:
+            self.obs.metrics.gauge("kg_store_bytes").set(store.nbytes)
         # crossover_batch is policy and rides in the spec; interpret mode
         # is environment and is NEVER passed here — backends re-resolve
         # it per call (see repro.kernels.device.default_interpret), so a
@@ -206,6 +222,29 @@ class SkewRouteSession:
             return self.pipeline.submit(
                 np.atleast_2d(np.asarray(scores_desc)),
                 payloads=payloads, n_valid=n_valid, self_scores=self_scores)
+
+    def submit_questions(self, topics, query_emb, cand_ids, n_cand,
+                         payloads: Optional[Sequence] = None):
+        """Route a batch of questions over the session's knowledge graph
+        and hand each, with its top-K triple ids, to its tier's runner.
+
+        ``topics``: [B] topic entity ids; ``query_emb``: [B, d] query
+        embeddings; ``cand_ids``: [B, N] ids of each question's candidate
+        triples (its retrieved subgraph), N at most ``store.max_cands``;
+        ``n_cand``: [B] real candidates per row (1 .. N), the rest ignored.
+        Every runner call receives a list of
+        :class:`~repro.serving.router_service.QuestionRecord`. Returns a
+        :class:`~repro.serving.router_service.QuestionDispatchResult`.
+        """
+        if self.pipeline is None or self.store is None or self.scorer is None:
+            raise RuntimeError(
+                "submit_questions needs a session built with runners=, "
+                "store= (a repro.retrieval.store.DeviceKG) and scorer= (the "
+                "triple scorer's weights)")
+        with self._lock:
+            return self.pipeline.submit_questions(
+                self.store, self.scorer, topics, query_emb, cand_ids, n_cand,
+                payloads=payloads)
 
     def flush(self) -> int:
         """Drain partial micro-batches; returns requests executed."""
@@ -452,11 +491,16 @@ class SkewRouteSession:
 
 def build(spec: RouteSpec,
           runners: Optional[Runners] = None,
-          obs: Optional[Observability] = None) -> SkewRouteSession:
+          obs: Optional[Observability] = None,
+          store=None, scorer: Optional[Mapping] = None) -> SkewRouteSession:
     """The one entry point: declarative spec -> running session.
 
     ``obs``: an :class:`repro.obs.Observability` plane to record
     metrics + request traces into (runtime configuration, like
     ``runners`` — never part of the spec). Default: the no-op plane.
+    ``store``: a :class:`repro.retrieval.store.DeviceKG` and ``scorer``:
+    the triple scorer's weights (``w1_t``, ``w1_q``, ``b1``, ``w2``,
+    ``b2``), for :meth:`SkewRouteSession.submit_questions`.
     """
-    return SkewRouteSession(spec, runners=runners, obs=obs)
+    return SkewRouteSession(spec, runners=runners, obs=obs, store=store,
+                            scorer=scorer)

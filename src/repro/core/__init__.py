@@ -26,6 +26,7 @@ from repro.core.router import (  # noqa: F401
     route_all_metrics,
     route_binary,
     route_from_difficulty,
+    route_kg,
     route_retrieved,
     route_retrieved_staged,
 )

@@ -369,6 +369,137 @@ def route_retrieved(feats: jax.Array, query_emb: jax.Array,
                                 tiers=tiers, difficulty=diff, metrics=metrics)
 
 
+# -- end-to-end from the on-device knowledge graph ------------------------------
+
+#: Leading int32 columns of a packed question-decision buffer: the
+#: float32 decision of :func:`pack_decision` as its bits, then n_valid.
+_KG_HEAD = N_METRICS + 2
+
+
+def subgraph_hops(topics: jax.Array, heads: jax.Array, tails: jax.Array,
+                  valid: jax.Array, max_hops: int):
+    """Hop distances of each candidate triple's head and tail from the
+    question's topic, over the question's own valid candidate triples as
+    directed edges head -> tail: ``[B]`` topics, ``[B, N]`` entity ids and
+    validity -> two ``[B, N]`` int32 arrays, exact for 0 .. max_hops - 1
+    and ``max_hops`` for farther or unreachable.
+
+    A node's distance is one more than the least distance of the head of
+    an edge into it. Every node of a shortest path but the last is the
+    head of a candidate triple, so ``max_hops - 1`` rounds over the heads
+    make every head within ``max_hops - 1`` exact; one more round gives
+    the tails. Nodes are matched by entity id, ``[B, N, N]`` comparisons
+    a round."""
+    top = topics[:, None]
+    into_head = (tails[:, :, None] == heads[:, None, :]) & valid[:, :, None]
+    into_tail = (tails[:, :, None] == tails[:, None, :]) & valid[:, :, None]
+
+    def relax(dist, into, via):
+        step = jnp.min(jnp.where(into, via[:, :, None] + 1, max_hops), axis=1)
+        return jnp.minimum(dist, step)
+
+    dh = jnp.where(heads == top, 0, max_hops).astype(jnp.int32)
+    for _ in range(max_hops - 1):
+        dh = relax(dh, into_head, dh)
+    dt = jnp.where(tails == top, 0, max_hops).astype(jnp.int32)
+    return dh, relax(dt, into_tail, dh)
+
+
+def kg_features(ent, rel, heads, rels, tails, topics, query_emb, cand_ids,
+                n_cand) -> jax.Array:
+    """A question batch's candidate features, gathered on the device:
+    ``[B, N]`` candidate triple ids (valid below ``n_cand``) -> ``[B, N,
+    3d + 2(MAX_DDE_HOPS+1) + 4]``: head, relation and tail rows, the DDE
+    one-hots of head and tail over the question's subgraph, and q.h, q.r,
+    q.t, q.(h+r) over sqrt(d) at ``HIGHEST`` precision. A padded slot
+    reads triple 0, so every gather stays in bounds; it is no edge of the
+    subgraph, and the decision masks it out."""
+    from repro.kernels.triple_score.kernel import PRECISION
+    from repro.retrieval.scorer import MAX_DDE_HOPS
+
+    n = cand_ids.shape[1]
+    valid = jnp.arange(n, dtype=jnp.int32)[None, :] < n_cand[:, None]
+    ids = jnp.where(valid, cand_ids, 0)
+    hid, rid, tid = heads[ids], rels[ids], tails[ids]
+    h, r, t = ent[hid], rel[rid], ent[tid]
+    dh, dt = subgraph_hops(topics, hid, tid, valid, MAX_DDE_HOPS)
+    eye = jnp.eye(MAX_DDE_HOPS + 1, dtype=jnp.float32)
+    qv = query_emb.astype(jnp.float32) / np.sqrt(np.float32(ent.shape[1]))
+
+    def sim(x):
+        return jnp.einsum("bnd,bd->bn", x, qv, precision=PRECISION)
+
+    sims = jnp.stack([sim(h), sim(r), sim(t), sim(h + r)], axis=-1)
+    return jnp.concatenate([h, r, t, eye[dh], eye[dt], sims], axis=-1)
+
+
+def pack_kg(tiers, metrics, n_valid, probs, triple_ids) -> jax.Array:
+    """One ``[B, 6 + 2K]`` int32 buffer: the decision of
+    :func:`pack_decision` and the top-K scores as their float32 bits,
+    ``n_valid`` and the top-K triple ids as they are. Integer columns
+    keep the ids exact at any graph size."""
+    def bits(x):
+        return jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    return jnp.concatenate(
+        [bits(pack_decision(tiers, metrics)), n_valid[:, None], bits(probs),
+         triple_ids], axis=-1)
+
+
+def unpack_kg(buf: np.ndarray, metric: str):
+    """Host inverse of :func:`pack_kg` on a read-back buffer: ``(tiers,
+    difficulty, metrics, n_valid, probs, triple_ids)``."""
+    k = (buf.shape[1] - _KG_HEAD) // 2
+    floats = buf.view(np.float32)
+    tiers, diff, metrics = unpack_decision(floats[:, :N_METRICS + 1], metric)
+    return (tiers, diff, metrics, buf[:, N_METRICS + 1].copy(),
+            floats[:, _KG_HEAD:_KG_HEAD + k], buf[:, _KG_HEAD + k:])
+
+
+@functools.partial(jax.jit, static_argnames=("top_k", "metric", "p_cdf",
+                                             "use_kernels", "interpret",
+                                             "tile"))
+def _kg_program(ent, rel, heads, rels, tails, w1_t, w1_q, b1, w2, b2,
+                thresholds, topics, query_emb, cand_ids, n_cand, *,
+                top_k: int, metric: str, p_cdf: float, use_kernels: bool,
+                interpret: bool, tile: int) -> jax.Array:
+    """Candidate triple ids -> features from the on-device tables ->
+    triple scores -> top-k -> skew metrics -> tiers, and the top-k triple
+    ids, as ONE jitted program with ONE output (:func:`pack_kg`)."""
+    feats = kg_features(ent, rel, heads, rels, tails, topics, query_emb,
+                        cand_ids, n_cand)
+    logits = score_candidates(feats, query_emb, w1_t, w1_q, b1, w2, b2,
+                              use_kernels=use_kernels, interpret=interpret,
+                              tile=tile)
+    idx, probs, nv, tiers, _, metrics = topk_sigmoid_decision(
+        logits, thresholds, n_cand, top_k=top_k, metric=metric, p_cdf=p_cdf,
+        ragged=True, use_kernel=use_kernels, interpret=interpret)
+    ids = jnp.take_along_axis(cand_ids, idx, axis=1)
+    ids = jnp.where(jnp.arange(top_k)[None, :] < nv[:, None], ids, -1)
+    return pack_kg(tiers, metrics, nv, probs, ids)
+
+
+def route_kg(store, params: Mapping[str, jax.Array], config: RouterConfig,
+             topics: jax.Array, query_emb: jax.Array, cand_ids: jax.Array,
+             n_cand: jax.Array, interpret: Optional[bool] = None,
+             use_kernels: bool = True, tile: int = 128) -> jax.Array:
+    """Questions in, decisions and top-K triple ids out, from a
+    :class:`repro.retrieval.store.DeviceKG`: ``topics`` [B] and
+    ``cand_ids`` [B, N] int32, ``query_emb`` [B, d], ``n_cand`` [B] real
+    candidates (1 .. N). Returns the packed buffer (:func:`unpack_kg`).
+    ``use_kernels=False`` runs the same program on the XLA reference ops.
+    """
+    if interpret is None:
+        interpret = default_interpret()
+    return _kg_program(
+        store.ent, store.rel, store.heads, store.rels, store.tails,
+        params["w1_t"], params["w1_q"], params["b1"], params["w2"],
+        params["b2"], _thresholds_array(config.thresholds), topics,
+        query_emb, cand_ids, n_cand,
+        top_k=min(config.top_k, cand_ids.shape[1]), metric=config.metric,
+        p_cdf=config.cumulative_p, use_kernels=use_kernels,
+        interpret=interpret, tile=tile)
+
+
 def route_retrieved_staged(feats, query_emb, params: Mapping,
                            config: RouterConfig,
                            n_cand=None) -> RetrievedRouteResult:

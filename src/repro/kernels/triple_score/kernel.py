@@ -20,8 +20,10 @@ the array's): the per-query bias is a [Q, 1, H] array read as a squeezed
 a [Q, 1, N] array; ``w2`` enters as a [1, H] row and ``b2`` as an SMEM
 scalar. The second layer is an NT dot of the ``w2`` row against ``h``,
 which gives the [1, tile] score row directly.
-VMEM: W1_t [Dt, H] + tile [128, Dt] + h [128, H] + two [1, H] rows — for
-Dt=1156, H=1024 (paper-scale) ≈ 5 MiB, within budget.
+VMEM: W1_t [Dt, H] + tile [128, Dt] + h [128, H] + two [1, H] rows, each
+block double-buffered — for Dt=1156, H=1024 (paper-scale) ≈ 11 MiB, within
+the 16 MiB default; at gte-large width (Dt=3086) ≈ 30 MiB, so the call
+raises its scoped-VMEM limit (`_scoped_vmem`).
 
 Precision: every dot here and in the XLA reference (`ref.py`) runs at
 ``Precision.HIGHEST``. A TPU f32 dot at default precision is a single bf16
@@ -41,6 +43,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TILE = 128
 PRECISION = jax.lax.Precision.HIGHEST
+#: Mosaic's default scoped-VMEM limit on a TPU v5e; a v5e core has 128 MiB.
+DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+#: the most scoped VMEM a call may ask for, leaving the rest to the compiler
+MAX_SCOPED_VMEM = 100 * 1024 * 1024
 
 _NT = (((1,), (1,)), ((), ()))  # contract the last dim of both operands
 
@@ -60,6 +66,21 @@ def _score_kernel(t_ref, qb_ref, w1_ref, w2_ref, b2_ref, o_ref):
 
 def _pad_to(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
+
+
+def _scoped_vmem(tile: int, dt: int, h_dim: int):
+    """Compiler parameters that raise the scoped-VMEM limit where the
+    kernel's blocks need more than the default: every block twice (the
+    pipeline's two buffers, the resident ``W1_t`` included) and the
+    ``[tile, H]`` hidden activations, with a quarter for Mosaic's own
+    scratch. At gte-large width (Dt=3086, H=1024) ``W1_t`` alone is 12 MiB,
+    and the default 16 MiB does not hold two of it. None: the default
+    holds."""
+    blocks = (tile * dt + dt * h_dim + 2 * h_dim + tile) * 4
+    need = int(1.25 * (2 * blocks + 2 * tile * h_dim * 4))
+    if need <= DEFAULT_SCOPED_VMEM:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=min(need, MAX_SCOPED_VMEM))
 
 
 def _score_call(triple_feats, t_spec: pl.BlockSpec, query_emb, w1_t, w1_q,
@@ -88,6 +109,7 @@ def _score_call(triple_feats, t_spec: pl.BlockSpec, query_emb, w1_t, w1_q,
         out_specs=pl.BlockSpec((None, 1, tile), lambda iq, it: (iq, 0, it)),
         out_shape=jax.ShapeDtypeStruct((q_count, 1, n_out), jnp.float32),
         interpret=interpret,
+        compiler_params=_scoped_vmem(tile, dt, h_dim),
         name=name,
     )(triple_feats, q_bias, w1_t, w2.reshape(1, h_dim),
       b2.astype(jnp.float32))

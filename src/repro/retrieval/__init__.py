@@ -1,2 +1,3 @@
 """KG retrieval substrate: triple store, SubgraphRAG-style scorer,
-neighbor sampler, and the synthetic Freebase-like KGQA benchmark."""
+neighbor sampler, the synthetic Freebase-like KGQA benchmark, and the
+store held on the device (`store.DeviceKG`)."""

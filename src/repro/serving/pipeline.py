@@ -45,6 +45,7 @@ from repro.obs import NULL_OBS, int_keyed, str_keyed
 from repro.serving import _deprecation
 from repro.serving.admission import AdmissionController
 from repro.serving.router_service import (BatchDispatchResult,
+                                          QuestionDispatchResult,
                                           SkewRouteDispatcher)
 from repro.serving.scheduler import MicroBatchQueue
 
@@ -215,55 +216,88 @@ class ServingPipeline:
         if payloads is not None and len(payloads) != scores.shape[0]:
             raise ValueError(f"{scores.shape[0]} score rows but "
                              f"{len(payloads)} payloads")
-        obs_on = self.obs.enabled
         with self.obs.tracer.span("submit", batch=int(scores.shape[0])):
             res: BatchDispatchResult = self.dispatcher.dispatch_batch(
                 scores, n_valid=n_valid, return_details=True,
                 self_scores=self_scores)
-            exec_tiers = res.tiers
-            if self.admission is not None:
-                new_config = self.admission.control_step()
-                if new_config is not None:
-                    self.dispatcher.apply_config(new_config)
-                    self.telemetry.n_recalibrations += 1
-                    self._m_recal.inc()
-                # request_cost (when the policy priced per request —
-                # cascade stage bills, depth-priced prompts) flows into
-                # the budget EWMA so admission reacts to what the
-                # decision actually costs, not the flat per-tier price.
-                exec_tiers, n_spilled = self.admission.apply(
-                    res.tiers, res.difficulty, request_cost=res.request_cost)
-                self.telemetry.n_spilled += n_spilled
-                self._m_spilled.inc(n_spilled)
-                if obs_on and n_spilled:
-                    moved = np.flatnonzero(exec_tiers != res.tiers)
-                    self.obs.tracer.event(
-                        "spill",
-                        request_ids=[res.first_id + int(i) for i in moved],
-                        **{"from": res.tiers[moved].tolist(),
-                           "to": exec_tiers[moved].tolist()})
+            exec_tiers = self._admit(res)
             with self.obs.tracer.span("handoff"):
                 # per-request records are lazy; only build them when they
                 # ARE the payloads — with explicit payloads the tier array
                 # is all we need
-                items = payloads if payloads is not None else res.records
-                self.telemetry.n_submitted += len(items)
-                self._m_submitted.inc(len(items))
-                if res.recalibrated:
-                    self.telemetry.n_recalibrations += 1
-                    self._m_recal.inc()
-                for i, (tier, item) in enumerate(zip(exec_tiers.tolist(),
-                                                     items)):
-                    self.telemetry.tier_counts[tier] += 1
-                    self._m_tiers[tier].inc()
-                    if obs_on:
-                        self._queued_ids[tier].append(res.first_id + i)
-                    for full in self.queues[tier].push(item):
-                        self._run(tier, full)
-                if obs_on:
-                    for tier, g in enumerate(self._g_pending):
-                        g.set(len(self.queues[tier]))
+                self._hand_off(res, exec_tiers, payloads
+                               if payloads is not None else res.records)
         return res
+
+    def submit_questions(self, store, scorer_params, topics, query_emb,
+                         cand_ids, n_cand,
+                         payloads: Optional[Sequence] = None
+                         ) -> QuestionDispatchResult:
+        """Route a batch of questions over a knowledge graph on the
+        device (`SkewRouteDispatcher.dispatch_kg`) and pump full
+        micro-batches: each tier runner receives one
+        :class:`~repro.serving.router_service.QuestionRecord` per question
+        (its id, tier, top-K triple ids and ``payloads[i]``). Admission
+        applies as in :meth:`submit`."""
+        b = len(topics)
+        if payloads is not None and len(payloads) != b:
+            raise ValueError(f"{b} questions but {len(payloads)} payloads")
+        with self.obs.tracer.span("submit", batch=b):
+            res = self.dispatcher.dispatch_kg(store, scorer_params, topics,
+                                              query_emb, cand_ids, n_cand)
+            exec_tiers = self._admit(res.result)
+            with self.obs.tracer.span("handoff"):
+                self._hand_off(res.result, exec_tiers,
+                               res.handoffs(exec_tiers, payloads))
+        return res
+
+    def _admit(self, res: BatchDispatchResult) -> np.ndarray:
+        """The tiers a dispatched batch executes on: the decisions, or,
+        with an admission controller, after its feedback tick and spill."""
+        if self.admission is None:
+            return res.tiers
+        new_config = self.admission.control_step()
+        if new_config is not None:
+            self.dispatcher.apply_config(new_config)
+            self.telemetry.n_recalibrations += 1
+            self._m_recal.inc()
+        # request_cost (when the policy priced per request — cascade stage
+        # bills, depth-priced prompts) flows into the budget EWMA so
+        # admission reacts to what the decision actually costs, not the
+        # flat per-tier price.
+        exec_tiers, n_spilled = self.admission.apply(
+            res.tiers, res.difficulty, request_cost=res.request_cost)
+        self.telemetry.n_spilled += n_spilled
+        self._m_spilled.inc(n_spilled)
+        if self.obs.enabled and n_spilled:
+            moved = np.flatnonzero(exec_tiers != res.tiers)
+            self.obs.tracer.event(
+                "spill",
+                request_ids=[res.first_id + int(i) for i in moved],
+                **{"from": res.tiers[moved].tolist(),
+                   "to": exec_tiers[moved].tolist()})
+        return exec_tiers
+
+    def _hand_off(self, res: BatchDispatchResult, exec_tiers: np.ndarray,
+                  items: Sequence) -> None:
+        """Queue each item on its tier and run every micro-batch that
+        fills."""
+        obs_on = self.obs.enabled
+        self.telemetry.n_submitted += len(items)
+        self._m_submitted.inc(len(items))
+        if res.recalibrated:
+            self.telemetry.n_recalibrations += 1
+            self._m_recal.inc()
+        for i, (tier, item) in enumerate(zip(exec_tiers.tolist(), items)):
+            self.telemetry.tier_counts[tier] += 1
+            self._m_tiers[tier].inc()
+            if obs_on:
+                self._queued_ids[tier].append(res.first_id + i)
+            for full in self.queues[tier].push(item):
+                self._run(tier, full)
+        if obs_on:
+            for tier, g in enumerate(self._g_pending):
+                g.set(len(self.queues[tier]))
 
     def flush(self) -> int:
         """Drain partial micro-batches (burst tail / shutdown); returns

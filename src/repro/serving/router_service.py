@@ -39,7 +39,8 @@ import numpy as np
 
 from repro.core.calibrate import calibrate_multi_tier
 from repro.core.cost import CostModel
-from repro.core.router import RouteBatchResult, RouterConfig, unpack_decision
+from repro.core.router import (RouteBatchResult, RouterConfig,
+                               unpack_decision, unpack_kg)
 from repro.core.streaming_calibrate import StreamingCalibrator
 from repro.obs import NULL_OBS, str_keyed, int_keyed
 from repro.serving import _deprecation
@@ -103,6 +104,44 @@ class RetrievedDispatchResult:
 
 
 @dataclasses.dataclass
+class QuestionRecord:
+    """What a tier runner receives for one question: its request id, the
+    tier it runs on, its top-K triple ids (best first) and the caller's
+    payload, if any."""
+
+    request_id: int
+    tier: int
+    triple_ids: np.ndarray    # [n_valid] int32
+    payload: object = None
+
+
+@dataclasses.dataclass
+class QuestionDispatchResult:
+    """A question batch's decisions (:class:`BatchDispatchResult`) and its
+    top-K retrieval: triple ids from the store, sigmoid scores, valid
+    prefix (ids are -1 and scores 0 past it)."""
+
+    result: BatchDispatchResult
+    triple_ids: np.ndarray    # [B, K] int32
+    probs: np.ndarray         # [B, K] float32, descending
+    n_valid: np.ndarray       # [B] int32
+
+    @property
+    def tiers(self) -> np.ndarray:
+        return self.result.tiers
+
+    def handoffs(self, tiers: np.ndarray,
+                 payloads: Optional[Sequence] = None) -> list:
+        """One :class:`QuestionRecord` per question, for ``tiers`` (the
+        tiers the questions execute on)."""
+        first = self.result.first_id
+        payloads = payloads if payloads is not None else [None] * len(tiers)
+        return [QuestionRecord(first + i, t, self.triple_ids[i, :nv], p)
+                for i, (t, nv, p) in enumerate(zip(
+                    tiers.tolist(), self.n_valid.tolist(), payloads))]
+
+
+@dataclasses.dataclass
 class DispatcherStats:
     n_requests: int = 0
     n_batches: int = 0
@@ -137,6 +176,16 @@ class DispatcherStats:
         self.tier_counts = int_keyed(state["tier_counts"])
         self.total_cost = float(state["total_cost"])
         self.mean_difficulty = float(state["mean_difficulty"])
+
+
+def _cut_to_depths(depths, n_valid, probs, ids):
+    """Depth routing: the candidate set each request SHIPS is its routed
+    depth. The valid prefix shrinks to it, and the scores past it read 0
+    and the ids -1, so downstream consumers can't read truncated rows."""
+    n_valid = np.minimum(n_valid, depths).astype(np.int32)
+    past = np.arange(probs.shape[1])[None, :] >= n_valid[:, None]
+    return (n_valid, np.where(past, 0.0, probs).astype(probs.dtype),
+            np.where(past, -1, ids).astype(np.int32))
 
 
 class SkewRouteDispatcher:
@@ -189,6 +238,7 @@ class SkewRouteDispatcher:
                          for t in range(router.n_tiers)]
         self._m_h2d = m.counter("dispatch_transfers_total", direction="h2d")
         self._m_d2h = m.counter("dispatch_transfers_total", direction="d2h")
+        self._m_kg_cands = m.counter("kg_candidates_total")
 
     def _obs_resync(self) -> None:
         """Point the registry's dispatcher mirrors at the (restored)
@@ -387,13 +437,8 @@ class SkewRouteDispatcher:
                 first_id, metric_name, recalibrated = self._record_batch(
                     decision.tiers, diff, decision, backend_tiers=tiers)
         if decision.depths is not None:
-            # Depth-routing: the candidate set each request SHIPS is the
-            # routed depth — shrink the valid prefix and zero the probs
-            # past it so downstream consumers can't read truncated rows.
-            nv_out = np.minimum(nv_out, decision.depths).astype(np.int32)
-            probs = np.where(
-                np.arange(probs.shape[1])[None, :] < nv_out[:, None],
-                probs, 0.0).astype(probs.dtype)
+            nv_out, probs, indices = _cut_to_depths(decision.depths, nv_out,
+                                                    probs, indices)
         return RetrievedDispatchResult(
             result=BatchDispatchResult(
                 tiers=decision.tiers, difficulty=diff,
@@ -404,6 +449,90 @@ class SkewRouteDispatcher:
             indices=indices,
             probs=probs,
             n_valid=nv_out)
+
+    def dispatch_kg(self, store, scorer_params, topics, query_emb,
+                    cand_ids, n_cand) -> QuestionDispatchResult:
+        """Questions over a knowledge graph on the device: ONE device
+        program (`repro.core.router.route_kg`) gathers each candidate's
+        features from ``store`` (a `repro.retrieval.store.DeviceKG`),
+        scores them, keeps the top K and decides; one transfer of the
+        inputs, one read of the packed result.
+
+        ``topics``: [B] topic entities; ``query_emb``: [B, d];
+        ``cand_ids``: [B, N] candidate triple ids, N <= ``store.max_cands``,
+        of which the first ``n_cand[i]`` (1 .. N) of row i are real. Each
+        call is padded to ``store.max_cands`` slots and a batch bucket.
+        """
+        if not hasattr(self.backend, "route_kg"):
+            raise TypeError(
+                f"difficulty backend {self.backend.name!r} has no route_kg; "
+                f"question dispatch needs one of the built-in backends "
+                f"(oracle | pallas | fused | auto) or a custom backend "
+                f"implementing it")
+        topics = np.asarray(topics)
+        cand = np.asarray(cand_ids)
+        nc = np.asarray(n_cand, np.int32)
+        qemb = np.asarray(query_emb, np.float32)
+        b, n = cand.shape
+        if not (topics.shape == nc.shape == (b,) and
+                qemb.shape == (b, store.d_emb)):
+            raise ValueError(
+                f"{b} questions of candidate ids, but topics {topics.shape}, "
+                f"n_cand {nc.shape} and query_emb {qemb.shape} (width "
+                f"{store.d_emb})")
+        if n > store.max_cands or nc.min(initial=1) < 1 or \
+                nc.max(initial=1) > n:
+            raise ValueError(
+                f"candidate counts must lie in 1 .. {n} and the store holds "
+                f"{store.max_cands} slots; got {n} columns, counts "
+                f"{nc.min(initial=1)} .. {nc.max(initial=1)}")
+        tracer = self.obs.tracer
+        obs_on = self.obs.enabled
+        with tracer.span("dispatch_kg", batch=b):
+            t0 = self.obs.clock.now() if obs_on else 0.0
+            with tracer.span("launch"):
+                bpad = bucket_size(b, BATCH_BUCKETS)
+                ids = np.zeros((bpad, store.max_cands), np.int32)
+                ids[:b, :n] = np.where(np.arange(n)[None, :] < nc[:, None],
+                                       cand, 0)
+                if ids.min() < 0 or ids.max() >= store.n_triples or \
+                        topics.min(initial=0) < 0 or \
+                        topics.max(initial=0) >= store.n_entities:
+                    raise ValueError(
+                        f"candidate triple ids must lie in 0 .. "
+                        f"{store.n_triples - 1} and topics in 0 .. "
+                        f"{store.n_entities - 1}")
+                top = np.zeros(bpad, np.int32)
+                top[:b] = topics
+                q = np.zeros((bpad, store.d_emb), np.float32)
+                q[:b] = qemb
+                ncp = np.ones(bpad, np.int32)  # padded rows: one slot
+                ncp[:b] = nc
+                metric = self.router.metric
+                packed = self.backend.route_kg(
+                    store, scorer_params, self.router,
+                    *self._put(top, q, ids, ncp))
+            with tracer.span("pull"):
+                (buf,) = self._pull(b, packed)
+                tiers, diff, metrics, nv_out, probs, triple_ids = unpack_kg(
+                    buf, metric)
+            if obs_on:
+                self._m_dispatch_s.observe(self.obs.clock.now() - t0)
+            self._m_kg_cands.inc(int(nc.sum()))
+            with tracer.span("decide"):
+                decision = self.policy.decide(tiers, diff, metrics)
+                first_id, metric_name, recalibrated = self._record_batch(
+                    decision.tiers, diff, decision, backend_tiers=tiers)
+        if decision.depths is not None:
+            nv_out, probs, triple_ids = _cut_to_depths(
+                decision.depths, nv_out, probs, triple_ids)
+        return QuestionDispatchResult(
+            result=BatchDispatchResult(
+                tiers=decision.tiers, difficulty=diff, metrics=metrics,
+                first_id=first_id, metric=metric_name,
+                recalibrated=recalibrated,
+                request_cost=decision.request_cost, depths=decision.depths),
+            triple_ids=triple_ids, probs=probs, n_valid=nv_out)
 
     def _record_batch(self, tiers: np.ndarray, diff: np.ndarray,
                       decision=None, backend_tiers=None

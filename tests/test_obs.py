@@ -418,6 +418,55 @@ def test_served_path_spans_land_in_the_profiler_trace(plane, tmp_path):
     assert forest[2][1] == [["repro.dispatch_retrieved", stages]]
 
 
+def test_question_dispatch_spans_counters_and_store_gauge(tmp_path):
+    """``submit_questions``: its dispatch span ``dispatch_kg`` under the
+    pipeline's ``submit``, one transfer each way per call, the real
+    candidates scored, and the store's bytes."""
+    import jax
+    from jax.profiler import TraceAnnotation
+    from repro.retrieval.store import DeviceKG
+
+    rng = np.random.default_rng(14)
+    d, h, n_ent, n_tri = 8, 16, 50, 400
+    store = DeviceKG.put(rng.normal(0, 1, (n_ent, d)), rng.normal(0, 1, (5, d)),
+                         rng.integers(0, n_ent, n_tri),
+                         rng.integers(0, 5, n_tri), rng.integers(0, n_ent, n_tri),
+                         max_cands=32)
+    params = {k: rng.normal(0, 0.3, s).astype(np.float32)
+              for k, s in (("w1_t", (3 * d + 14, h)), ("w1_q", (d, h)),
+                           ("b1", (h,)), ("w2", (h, 1)), ("b2", (1,)))}
+    obs = Observability(clock=ManualClock())
+    session = build(mk_spec(top_k=10, micro_batch=2),
+                    runners={0: lambda b: b, 1: lambda b: b}, obs=obs,
+                    store=store, scorer=params)
+    assert obs.metrics.value("kg_store_bytes") == store.nbytes == \
+        4 * (n_ent * d + 5 * d + 3 * n_tri)
+    n_cand = np.asarray([32, 3, 17])
+
+    def call():
+        return session.submit_questions(
+            rng.integers(0, n_ent, 3), rng.normal(0, 1, (3, d)),
+            rng.integers(0, n_tri, (3, 32)), n_cand)
+    call()                            # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TraceAnnotation("bench.call"):
+            before = session.pipeline.telemetry.n_microbatches
+            call()
+            n_exec = session.pipeline.telemetry.n_microbatches - before
+    finally:
+        jax.profiler.stop_trace()
+    stages = [["repro.launch", []], ["repro.pull", []], ["repro.decide", []]]
+    assert _forest(_host_events(tmp_path)) == [["bench.call", [[
+        "repro.submit", [
+            ["repro.dispatch_kg", stages],
+            ["repro.handoff", [["repro.execute", []]] * n_exec]]]]]]
+    for direction in ("h2d", "d2h"):
+        assert obs.metrics.value("dispatch_transfers_total",
+                                 direction=direction) == 2
+    assert obs.metrics.value("kg_candidates_total") == 2 * n_cand.sum()
+
+
 def test_null_plane_records_no_events_on_the_served_path():
     session = build(mk_spec(), runners={0: lambda b: b, 1: lambda b: b})
     rng = np.random.default_rng(12)

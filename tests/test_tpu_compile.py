@@ -108,3 +108,25 @@ def test_fused_retrieve_to_decision_compiles(one_chip):
     assert text.count("tpu_custom_call") >= 2  # scoring + skew kernels
     assert sorted(_kernel_ops(text)) == ["skew_metrics",
                                          "triple_score_batched"]
+
+
+def test_question_program_compiles_at_gte_large_width(one_chip):
+    """`route_kg` at bucket 64 over a store of NSM's WebQSP Freebase size
+    (1,441,420 x 1,024 entity table): the gathers, the scorer at Dt=3086
+    (whose blocks need more than the default scoped VMEM) and the skew
+    kernel, in one program."""
+    n_ent, n_rel, n_tri, d, b, n = 1441420, 6102, 11_300_000, 1024, 64, 512
+    dt = 3 * d + 14
+
+    def program(*args):
+        return router._kg_program(*args, top_k=100, metric="gini",
+                                  p_cdf=0.95, use_kernels=True,
+                                  interpret=False, tile=128)
+    text = _compiled_text(
+        program, _shape(one_chip, (n_ent, d)), _shape(one_chip, (n_rel, d)),
+        *[_shape(one_chip, (n_tri,), jnp.int32)] * 3,
+        *_scorer_shapes(one_chip, dt, d, d), _shape(one_chip, (1,)),
+        _shape(one_chip, (b,), jnp.int32), _shape(one_chip, (b, d)),
+        _shape(one_chip, (b, n), jnp.int32), _shape(one_chip, (b,), jnp.int32))
+    assert sorted(_kernel_ops(text)) == ["skew_metrics",
+                                         "triple_score_batched"]
