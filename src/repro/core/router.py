@@ -298,14 +298,18 @@ def topk_sigmoid_decision(logits: jax.Array, thresholds: jax.Array,
 
 def score_candidates(feats: jax.Array, query_emb: jax.Array,
                      w1_t, w1_q, b1, w2, b2, *, use_kernels: bool,
-                     interpret: bool, tile: int) -> jax.Array:
+                     interpret: bool, tile: int,
+                     n_cand: Optional[jax.Array] = None) -> jax.Array:
     """[B, N, Dt] features + [B, Dq] queries -> [B, N] candidate logits
     (Pallas `triple_score` kernel or its XLA ref). Row-and-candidate
-    local: safe to shard over both the request and candidate axes."""
+    local: safe to shard over both the request and candidate axes.
+    ``n_cand`` ([B] real candidates) lets the kernel skip the tiles past
+    each row's count, whose logits are then the kernel's fill; the XLA
+    ref scores every slot. Either way the caller masks those slots."""
     if use_kernels:
         from repro.kernels.triple_score import kernel as ts_kernel
         return ts_kernel.triple_score_batched(
-            feats, query_emb, w1_t, w1_q, b1, w2, b2,
+            feats, query_emb, w1_t, w1_q, b1, w2, b2, n_cand=n_cand,
             tile=tile, interpret=interpret)
     from repro.kernels.triple_score.ref import triple_score_batched_ref
     return triple_score_batched_ref(feats, query_emb, w1_t, w1_q, b1, w2, b2)
@@ -325,7 +329,7 @@ def _retrieved_program(feats: jax.Array, query_emb: jax.Array,
     sees only the [B, K] retrieval output and the [B] tier ids."""
     logits = score_candidates(feats, query_emb, w1_t, w1_q, b1, w2, b2,
                               use_kernels=use_kernels, interpret=interpret,
-                              tile=tile)
+                              tile=tile, n_cand=n_cand if ragged else None)
     return topk_sigmoid_decision(
         logits, thresholds, n_cand, top_k=top_k, metric=metric,
         p_cdf=p_cdf, ragged=ragged, use_kernel=use_kernels,
@@ -469,7 +473,7 @@ def _kg_program(ent, rel, heads, rels, tails, w1_t, w1_q, b1, w2, b2,
                         cand_ids, n_cand)
     logits = score_candidates(feats, query_emb, w1_t, w1_q, b1, w2, b2,
                               use_kernels=use_kernels, interpret=interpret,
-                              tile=tile)
+                              tile=tile, n_cand=n_cand)
     idx, probs, nv, tiers, _, metrics = topk_sigmoid_decision(
         logits, thresholds, n_cand, top_k=top_k, metric=metric, p_cdf=p_cdf,
         ragged=True, use_kernel=use_kernels, interpret=interpret)
@@ -485,8 +489,10 @@ def route_kg(store, params: Mapping[str, jax.Array], config: RouterConfig,
     """Questions in, decisions and top-K triple ids out, from a
     :class:`repro.retrieval.store.DeviceKG`: ``topics`` [B] and
     ``cand_ids`` [B, N] int32, ``query_emb`` [B, d], ``n_cand`` [B] real
-    candidates (1 .. N). Returns the packed buffer (:func:`unpack_kg`).
-    ``use_kernels=False`` runs the same program on the XLA reference ops.
+    candidates (1 .. N; 0 marks a padding row, whose tiles the scorer
+    kernel skips and whose output is no decision). Returns the packed
+    buffer (:func:`unpack_kg`). ``use_kernels=False`` runs the same
+    program on the XLA reference ops.
     """
     if interpret is None:
         interpret = default_interpret()

@@ -42,6 +42,7 @@ from repro.core.cost import CostModel
 from repro.core.router import (RouteBatchResult, RouterConfig,
                                unpack_decision, unpack_kg)
 from repro.core.streaming_calibrate import StreamingCalibrator
+from repro.kernels.triple_score.kernel import DEFAULT_TILE as SCORER_TILE
 from repro.obs import NULL_OBS, str_keyed, int_keyed
 from repro.serving import _deprecation
 from repro.serving.scheduler import bucket_size
@@ -239,6 +240,10 @@ class SkewRouteDispatcher:
         self._m_h2d = m.counter("dispatch_transfers_total", direction="h2d")
         self._m_d2h = m.counter("dispatch_transfers_total", direction="d2h")
         self._m_kg_cands = m.counter("kg_candidates_total")
+        self._m_kg_tiles_scored = m.counter("kg_scorer_tiles_total",
+                                            state="scored")
+        self._m_kg_tiles_skipped = m.counter("kg_scorer_tiles_total",
+                                             state="skipped")
 
     def _obs_resync(self) -> None:
         """Point the registry's dispatcher mirrors at the (restored)
@@ -461,7 +466,10 @@ class SkewRouteDispatcher:
         ``topics``: [B] topic entities; ``query_emb``: [B, d];
         ``cand_ids``: [B, N] candidate triple ids, N <= ``store.max_cands``,
         of which the first ``n_cand[i]`` (1 .. N) of row i are real. Each
-        call is padded to ``store.max_cands`` slots and a batch bucket.
+        call is padded to ``store.max_cands`` slots and a batch bucket;
+        the bucket's padding rows hold no candidates, so the scorer kernel
+        skips all their tiles. ``kg_scorer_tiles_total{state}`` counts the
+        scorer grid's tiles of each call, scored and skipped.
         """
         if not hasattr(self.backend, "route_kg"):
             raise TypeError(
@@ -506,7 +514,7 @@ class SkewRouteDispatcher:
                 top[:b] = topics
                 q = np.zeros((bpad, store.d_emb), np.float32)
                 q[:b] = qemb
-                ncp = np.ones(bpad, np.int32)  # padded rows: one slot
+                ncp = np.zeros(bpad, np.int32)  # padded rows: no slots
                 ncp[:b] = nc
                 metric = self.router.metric
                 packed = self.backend.route_kg(
@@ -519,6 +527,10 @@ class SkewRouteDispatcher:
             if obs_on:
                 self._m_dispatch_s.observe(self.obs.clock.now() - t0)
             self._m_kg_cands.inc(int(nc.sum()))
+            scored = int((-(-nc // SCORER_TILE)).sum())
+            self._m_kg_tiles_scored.inc(scored)
+            self._m_kg_tiles_skipped.inc(
+                bpad * -(-store.max_cands // SCORER_TILE) - scored)
             with tracer.span("decide"):
                 decision = self.policy.decide(tiers, diff, metrics)
                 first_id, metric_name, recalibrated = self._record_batch(

@@ -63,6 +63,43 @@ def test_triple_score_sweep(n, dt, dq, h, q):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("tile,n", [(64, 300), (128, 300), (128, 512)])
+def test_triple_score_batched_skips_tiles_past_each_count(tile, n):
+    """Ragged grid: a row scores its first ceil(count / tile) tiles with the
+    full grid's arithmetic, bit for bit; its other slots read the fill.
+    Rows without candidates sit at the start, in the middle and in a run
+    at the end, where their steps hold an earlier row's block."""
+    from repro.kernels.triple_score import kernel
+    from repro.kernels.triple_score.ref import triple_score_batched_ref
+    counts = np.asarray([0, 1, tile - 1, 0, tile, tile + 1, n // 2 + 3, n,
+                         0, 0], np.int32)
+    b, dt, dq, h = len(counts), 36, 24, 64
+    ks = jax.random.split(jax.random.key(tile + n), 7)
+    args = (jax.random.normal(ks[0], (b, n, dt)),
+            jax.random.normal(ks[1], (b, dq)),
+            jax.random.normal(ks[2], (dt, h)) * 0.2,
+            jax.random.normal(ks[3], (dq, h)) * 0.2,
+            jax.random.normal(ks[4], (h,)) * 0.1,
+            jax.random.normal(ks[5], (h, 1)) * 0.2,
+            jax.random.normal(ks[6], (1,)))
+    full = np.asarray(kernel.triple_score_batched(*args, tile=tile,
+                                                  interpret=True))
+    np.testing.assert_allclose(full, triple_score_batched_ref(*args),
+                               rtol=1e-5, atol=1e-5)
+    compiled = kernel.triple_score_batched._cache_size()
+    every = np.asarray(kernel.triple_score_batched(
+        *args, n_cand=jnp.full(b, n, jnp.int32), tile=tile, interpret=True))
+    ragged = np.asarray(kernel.triple_score_batched(
+        *args, n_cand=jnp.asarray(counts), tile=tile, interpret=True))
+    # the counts are data: both count vectors ran one compiled program
+    assert kernel.triple_score_batched._cache_size() == compiled + 1
+    np.testing.assert_array_equal(every, full)
+    scored = -(-counts // tile) * tile
+    for row, stop in enumerate(scored):
+        np.testing.assert_array_equal(ragged[row, :stop], full[row, :stop])
+        assert (ragged[row, stop:] == kernel.FILL).all()
+
+
 @given(st.integers(1, 30), st.integers(5, 100), st.integers(0, 100))
 def test_skew_metrics_property(rows, k, seed):
     from repro.kernels.skew_metrics.kernel import skew_metrics

@@ -258,3 +258,45 @@ def test_depth_routing_ships_each_question_at_its_depth(kg):
     assert (res.triple_ids[past] == -1).all() and (res.probs[past] == 0).all()
     assert (res.triple_ids[~past] >= 0).all()
     assert sorted(len(r.triple_ids) for r in handed) == sorted(res.n_valid)
+
+
+class _InterpretedKernels:
+    """A backend whose question program runs the Pallas kernels under the
+    interpreter: the built-in backends trace the XLA ops off the chip, so
+    only this one drives the ragged scorer through `dispatch_kg` here."""
+
+    name = "interpreted-kernels"
+
+    def route_kg(self, store, params, config, *args):
+        return router.route_kg(store, params, config, *args, interpret=True,
+                               use_kernels=True)
+
+
+@pytest.mark.parametrize("b", [9, 37, 64])  # bucket 64: 55, 27, 0 pad rows
+def test_ragged_scorer_matches_xla_path_and_reference(kg, b):
+    """Through `dispatch_kg`, the scorer kernel that skips each row's tiles
+    past its count, and every tile of the bucket's padding rows, gives the
+    XLA path's and the reference's tiers and top-K triple ids."""
+    store, host, params = kg
+    rng = np.random.default_rng(200 + b)
+    topics, query, cand, n_cand = _questions(
+        rng, host, b, [512, 1, 127, 128, 129, 300, 37])
+    xla, _ = _session(store, params)
+    mid = np.sort(xla.submit_questions(
+        topics, query, cand, n_cand).result.difficulty)[b // 2 - 1:b // 2 + 1]
+    config = dataclasses.replace(xla.dispatcher.router,
+                                 thresholds=(float(mid.mean()),))
+    kernels, _ = _session(store, params)
+    kernels.dispatcher.backend = _InterpretedKernels()
+    for session in (xla, kernels):
+        session.dispatcher.apply_config(config)
+    want = xla.submit_questions(topics, query, cand, n_cand)
+    got = kernels.submit_questions(topics, query, cand, n_cand)
+    ids, probs, nv, tiers, _, _ = _reference(host, params, config, topics,
+                                             query, cand, n_cand)
+    for res in (want, got):
+        np.testing.assert_array_equal(res.n_valid, nv)
+        np.testing.assert_array_equal(res.triple_ids, ids)
+        np.testing.assert_array_equal(res.tiers, tiers)
+        np.testing.assert_allclose(res.probs, probs, rtol=0, atol=LOGIT_ATOL)
+    assert set(got.tiers.tolist()) == {0, 1}

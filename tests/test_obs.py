@@ -465,6 +465,42 @@ def test_question_dispatch_spans_counters_and_store_gauge(tmp_path):
         assert obs.metrics.value("dispatch_transfers_total",
                                  direction=direction) == 2
     assert obs.metrics.value("kg_candidates_total") == 2 * n_cand.sum()
+    # one 128-slot tile a question, of bucket 8's eight rows
+    assert obs.metrics.value("kg_scorer_tiles_total", state="scored") == 6
+    assert obs.metrics.value("kg_scorer_tiles_total", state="skipped") == 10
+
+
+def test_scorer_tile_counter_counts_the_ragged_grid():
+    """``kg_scorer_tiles_total{state}``: 37 questions of 385-512
+    candidates in bucket 64 over 512 slots score four 128-slot tiles each
+    (148) and skip the other 108 of the bucket's 256, the 27 padding
+    rows' tiles; then 9 questions of 1-129 candidates score 1 or 2."""
+    from repro.retrieval.store import DeviceKG
+
+    rng = np.random.default_rng(15)
+    d, h, n_ent, n_tri = 8, 16, 50, 400
+    store = DeviceKG.put(rng.normal(0, 1, (n_ent, d)), rng.normal(0, 1, (5, d)),
+                         rng.integers(0, n_ent, n_tri),
+                         rng.integers(0, 5, n_tri), rng.integers(0, n_ent, n_tri),
+                         max_cands=512)
+    params = {k: rng.normal(0, 0.3, s).astype(np.float32)
+              for k, s in (("w1_t", (3 * d + 14, h)), ("w1_q", (d, h)),
+                           ("b1", (h,)), ("w2", (h, 1)), ("b2", (1,)))}
+    obs = Observability(clock=ManualClock())
+    session = build(mk_spec(top_k=10, micro_batch=2),
+                    runners={0: lambda b: b, 1: lambda b: b}, obs=obs,
+                    store=store, scorer=params)
+
+    def tiles():
+        return [obs.metrics.value("kg_scorer_tiles_total", state=s)
+                for s in ("scored", "skipped")]
+    for b, counts, want in ((37, rng.integers(385, 513, 37), [148, 108]),
+                            (9, [1, 128, 129, 64, 1, 2, 3, 4, 5],
+                             [148 + 10, 108 + 246])):
+        session.submit_questions(
+            rng.integers(0, n_ent, b), rng.normal(0, 1, (b, d)),
+            rng.integers(0, n_tri, (b, 512)), np.asarray(counts))
+        assert tiles() == want
 
 
 def test_null_plane_records_no_events_on_the_served_path():

@@ -76,6 +76,21 @@ def test_triple_score_batched_compiles(one_chip, b, n, dt, dq, h):
     assert _kernel_ops(text) == ["triple_score_batched"]
 
 
+def test_ragged_triple_score_batched_compiles_at_gte_large_width(one_chip):
+    """The ragged grid at bucket 64, Dt = 3086 and 512 candidates: the
+    per-row counts are an operand given by its shape alone, so every count
+    vector runs this one executable."""
+    b, n, d = 64, 512, 1024
+    dt = 3 * d + 14
+    text = _compiled_text(
+        lambda *a: score_kernel.triple_score_batched(*a[:7], n_cand=a[7],
+                                                     interpret=False),
+        _shape(one_chip, (b, n, dt)), _shape(one_chip, (b, d)),
+        *_scorer_shapes(one_chip, dt, d, d),
+        _shape(one_chip, (b,), jnp.int32))
+    assert _kernel_ops(text) == ["triple_score_batched"]
+
+
 def test_triple_score_shared_candidates_compiles(one_chip):
     text = _compiled_text(
         lambda *a: score_kernel.triple_score(*a, interpret=False),
