@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.api import make_backend, resolve_backend_name
+from repro.api import make_backend
 from repro.core import skewness
 from repro.core.router import (RouterConfig, route_from_difficulty,
                                route_retrieved_staged)
@@ -403,7 +403,7 @@ def main() -> None:
             "metric": args.metric,
             "backend": {
                 "requested": args.backend,
-                "resolved": resolve_backend_name(args.backend),
+                "resolved": args.backend,
                 "crossover_batch": getattr(backend, "crossover_batch", None),
                 "interpret": bool(getattr(
                     backend, "effective_interpret",

@@ -29,14 +29,12 @@ from repro.api.backends import (  # noqa: F401
     DEFAULT_CROSSOVER_BATCH,
     AutoBackend,
     DifficultyBackend,
-    FusedBackend,
     OracleBackend,
     PallasBackend,
     available_backends,
     default_interpret,
     make_backend,
     register_backend,
-    resolve_backend_name,
 )
 from repro.api.spec import (  # noqa: F401
     ENVELOPE_VERSION,

@@ -1,30 +1,24 @@
 """Pluggable difficulty backends: ONE place that decides how skew metrics
-are computed.
-
-Before this module the interpret-vs-compiled choice and the oracle-vs-
-kernel choice were re-derived ad hoc wherever dispatch happened
-(`router_service`, `pipeline`, `launch/serve.py`). Now a
-:class:`DifficultyBackend` is a named, swappable policy object:
+are computed. A :class:`DifficultyBackend` is a named, swappable policy
+object:
 
 * ``oracle`` — the readable XLA path (`repro.core.skewness`, via the
   kernel's stacked ref), still fused into ONE jitted decision program
-  per batch. Ground truth; what offline evaluation wants — and the
-  fastest path at small batch sizes, where Pallas launch/interpret
-  overhead dominates.
-* ``pallas`` — the fused single-pass skew kernel
-  (`repro.kernels.skew_metrics`), interpret mode off-TPU.
-* ``fused``  — the end-to-end program: `triple_score` Pallas scoring ->
-  device top-k -> fused skew kernel -> threshold decision, chained in
-  one jitted computation (scores never leave HBM). Same scores-in
-  contract as ``pallas`` for :meth:`~DifficultyBackend.route_batch`,
-  plus :meth:`route_retrieved` for candidate-features-in routing and
-  :meth:`route_kg` for questions over a knowledge graph on the device.
+  per batch. Ground truth, and the fastest path at small batch sizes,
+  where Pallas launch/interpret overhead dominates.
+* ``pallas`` / ``fused`` — two names of one kernel backend
+  (:class:`PallasBackend`): the fused single-pass skew kernel
+  (`repro.kernels.skew_metrics`) for scores in, and the end-to-end
+  program (`triple_score` Pallas scoring -> device top-k -> skew kernel
+  -> threshold decision, one jitted computation) for candidate features
+  in (:meth:`route_retrieved`) and for questions over a knowledge graph
+  on the device (:meth:`route_kg`). Interpret mode off-TPU.
 * ``auto``   — the production policy: a measured BATCH-SIZE CROSSOVER.
-  Batches below ``crossover_batch`` go to the ``oracle`` program (which
-  wins at small B — the seed's kernel-everywhere policy LOST to the
-  oracle at B=1, 0.25–0.72x), batches at or above it go to the ``fused``
-  kernels. The crossover is a serializable
-  :class:`~repro.api.spec.RouteSpec` field so every replica agrees.
+  Batches below ``crossover_batch`` go to the ``oracle`` program, batches
+  at or above it to the ``fused`` kernels. The crossover is a
+  serializable :class:`~repro.api.spec.RouteSpec` field so every replica
+  agrees.
+* ``sharded`` — the mesh-sharded dispatch path (`repro.api.sharded`).
 
 Interpret-vs-compiled is NEVER stored: every backend defers to
 :func:`repro.kernels.device.default_interpret` at CALL time (compiled on
@@ -35,22 +29,19 @@ Every backend produces the SAME contract: ``[B, K]`` descending-sorted
 scores (+ optional ``[B]`` ``n_valid``) -> a full
 :class:`~repro.core.router.RouteBatchResult` with the raw ``[B, 4]``
 metric matrix in kernel column order, so the configured metric is always
-a column select — never a recompile — regardless of backend.
-
-Third-party backends (e.g. a mesh-sharded dispatch path, the ROADMAP's
-next step) register with :func:`register_backend` and become selectable
+a column select — never a recompile — regardless of backend. Other
+backends register with :func:`register_backend` and become selectable
 from a :class:`~repro.api.spec.RouteSpec` by name.
 
 Backends are POLICY-AGNOSTIC: they produce the threshold-tier ids plus
 the raw metric matrix, and the dispatcher's routing policy
-(`repro.policies` — cascade escalation, adaptive retrieval depth, mode
-selection) transforms that decision host-side afterwards. That layering
-is why every policy works identically under every backend, including
-``sharded``.
+(`repro.policies`) transforms that decision host-side afterwards, so
+every policy works identically under every backend.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Mapping, Optional, Protocol, runtime_checkable
 
 import jax
@@ -176,31 +167,25 @@ class OracleBackend(_SingleProgramBackend):
 
 
 class PallasBackend(_SingleProgramBackend):
-    """Fused single-pass skew kernel backend (`kernels.skew_metrics`)."""
+    """The kernel backend: the fused skew kernel (`kernels.skew_metrics`)
+    behind every entry, and `triple_score` scoring in front of it in
+    :meth:`route_retrieved` and :meth:`route_kg`. Registered as ``pallas``
+    and as ``fused``; ``name`` is the one it was made under."""
 
-    name = "pallas"
     _use_kernel = True
 
-
-class FusedBackend(PallasBackend):
-    """The end-to-end device program: Pallas `triple_score` scoring ->
-    device top-k -> fused skew kernel -> threshold decision, one jitted
-    computation. For pre-scored batches it is the ``pallas`` fast path;
-    :meth:`route_retrieved` is the scores-never-leave-HBM entry."""
-
-    name = "fused"
+    def __init__(self, interpret: Optional[bool] = None,
+                 name: str = "pallas"):
+        super().__init__(interpret)
+        self.name = name
 
 
 class AutoBackend:
     """Batch-size crossover policy: ``oracle`` below ``crossover_batch``,
-    the ``fused`` kernels at or above it.
-
-    This is the bugfix for the seed's B=1 regression: ``auto`` used to be
-    a blind alias for the kernel path, which loses to the single-program
-    oracle at small batches (0.25–0.72x at B=1 on the tracked bench).
-    The crossover is policy, not environment — it lives in
-    :class:`~repro.api.spec.RouteSpec` so replicas agree — while the
-    interpret-vs-compiled choice stays call-time per host.
+    the ``fused`` kernels at or above it. The crossover is policy, not
+    environment — it lives in :class:`~repro.api.spec.RouteSpec` so
+    replicas agree — while the interpret-vs-compiled choice stays
+    call-time per host.
     """
 
     name = "auto"
@@ -212,7 +197,7 @@ class AutoBackend:
                              f"got {crossover_batch}")
         self.crossover_batch = int(crossover_batch)
         self.oracle = OracleBackend()
-        self.fused = FusedBackend(interpret=interpret)
+        self.fused = PallasBackend(interpret=interpret, name="fused")
         # crossover-pick counters; replaced with live instruments when a
         # session attaches its observability plane (attach_obs)
         from repro.obs.registry import NULL_INSTRUMENT
@@ -278,13 +263,6 @@ def available_backends() -> tuple[str, ...]:
     return tuple(sorted(_REGISTRY)) + ("auto",)
 
 
-def resolve_backend_name(name: str = "auto") -> str:
-    """``auto`` is a first-class backend now (the crossover policy), no
-    longer an alias: it resolves to itself. Kept for callers that log or
-    validate backend names."""
-    return name
-
-
 def make_backend(name: str = "auto", **kwargs) -> DifficultyBackend:
     """Instantiate a difficulty backend by name (``auto`` = the batch-size
     crossover over oracle/fused — see module docstring; accepts
@@ -309,5 +287,5 @@ def _make_sharded(**kwargs) -> DifficultyBackend:
 
 register_backend("oracle", OracleBackend)
 register_backend("pallas", PallasBackend)
-register_backend("fused", FusedBackend)
+register_backend("fused", functools.partial(PallasBackend, name="fused"))
 register_backend("sharded", _make_sharded)

@@ -254,8 +254,7 @@ class RouteSpec:
         if self.crossover_batch < 1:
             raise ValueError(f"crossover_batch must be >= 1, "
                              f"got {self.crossover_batch}")
-        if (_backends.resolve_backend_name(self.backend)
-                not in _backends.available_backends()):
+        if self.backend not in _backends.available_backends():
             raise ValueError(
                 f"unknown difficulty backend {self.backend!r}; "
                 f"choose from {_backends.available_backends()}")

@@ -39,8 +39,7 @@ import numpy as np
 
 from repro.core.calibrate import calibrate_multi_tier
 from repro.core.cost import CostModel
-from repro.core.router import (RouteBatchResult, RouterConfig,
-                               unpack_decision, unpack_kg)
+from repro.core.router import RouterConfig, unpack_decision, unpack_kg
 from repro.core.streaming_calibrate import StreamingCalibrator
 from repro.kernels.triple_score.kernel import DEFAULT_TILE as SCORER_TILE
 from repro.obs import NULL_OBS, str_keyed, int_keyed
@@ -189,6 +188,15 @@ def _cut_to_depths(depths, n_valid, probs, ids):
             np.where(past, -1, ids).astype(np.int32))
 
 
+def _pad_rows(x: np.ndarray, bpad: int, fill=0) -> np.ndarray:
+    """``x`` with rows of ``fill`` appended up to the batch bucket's
+    ``bpad`` rows; ``x`` itself where it already has them."""
+    if len(x) == bpad:
+        return x
+    return np.concatenate(
+        [x, np.full((bpad - len(x),) + x.shape[1:], fill, x.dtype)])
+
+
 class SkewRouteDispatcher:
     def __init__(self, router: RouterConfig, tier_names: Sequence[str],
                  cost_model: Optional[CostModel] = None,
@@ -327,6 +335,49 @@ class SkewRouteDispatcher:
         self._m_d2h.inc()
         return [a[:b] for a in jax.device_get(device_arrays)]
 
+    def _backend_entry(self, name: str):
+        """The backend's ``name`` method; a TypeError where it has none."""
+        entry = getattr(self.backend, name, None)
+        if entry is None:
+            raise TypeError(
+                f"difficulty backend {self.backend.name!r} has no {name}; "
+                f"this dispatch needs one of the built-in backends (oracle | "
+                f"pallas | fused | auto) or a custom backend implementing it")
+        return entry
+
+    def _dispatch(self, span: str, b: int, inputs, launch, unpack,
+                  self_scores: Optional[np.ndarray] = None):
+        """What every dispatch entry shares, in the entry's ``span``:
+        ``inputs(bpad)`` pads the host inputs to the batch bucket; one
+        transfer; ``launch`` starts the backend's program on them; one
+        read; ``unpack`` gives tiers, difficulty, metrics and any
+        retrieval arrays (valid prefix, scores, ids); the policy decides
+        and the batch is recorded. Returns the :class:`BatchDispatchResult`
+        and the retrieval arrays, cut to the decision's depths if any."""
+        tracer = self.obs.tracer
+        obs_on = self.obs.enabled
+        with tracer.span(span, batch=b):
+            t0 = self.obs.clock.now() if obs_on else 0.0
+            with tracer.span("launch"):
+                bpad = bucket_size(b, BATCH_BUCKETS)
+                out = launch(*self._put(*inputs(bpad)))
+            with tracer.span("pull"):
+                tiers, diff, metrics, *retrieved = unpack(*self._pull(b, *out))
+            if obs_on:  # the pull forced the device sync above
+                self._m_dispatch_s.observe(self.obs.clock.now() - t0)
+            with tracer.span("decide"):
+                decision = self.policy.decide(tiers, diff, metrics,
+                                              self_scores=self_scores)
+                first_id, metric_name, recalibrated = self._record_batch(
+                    decision.tiers, diff, decision, backend_tiers=tiers)
+        if retrieved and decision.depths is not None:
+            retrieved = _cut_to_depths(decision.depths, *retrieved)
+        return BatchDispatchResult(
+            tiers=decision.tiers, difficulty=diff, metrics=metrics,
+            first_id=first_id, metric=metric_name, recalibrated=recalibrated,
+            request_cost=decision.request_cost,
+            depths=decision.depths), retrieved
+
     def dispatch(self, scores_desc: np.ndarray,
                  n_valid: Optional[int] = None) -> DispatchRecord:
         """Route one request — same fused kernel, batch of one (bucketed
@@ -349,111 +400,54 @@ class SkewRouteDispatcher:
         ``self_scores``: optional [B] engine self-uncertainty (higher =
         less confident) some policies (cascade) fold into the decision.
         """
-        tracer = self.obs.tracer
+        route, router = self._backend_entry("route_batch"), self.router
         scores = np.asarray(scores_desc)
         b, k = scores.shape
-        obs_on = self.obs.enabled
-        with tracer.span("dispatch", batch=b):
-            t0 = self.obs.clock.now() if obs_on else 0.0
-            with tracer.span("launch"):
-                bpad = bucket_size(b, BATCH_BUCKETS)
-                if bpad != b:
-                    scores = np.concatenate(
-                        [scores, np.zeros((bpad - b, k), scores.dtype)])
-                # always pass a concrete n_valid so every bucket shape
-                # compiles the kernel exactly once (None vs array would be
-                # two traces)
-                nv = np.full(bpad, k, np.int32)
-                if n_valid is not None:
-                    nv[:b] = np.asarray(n_valid, np.int32)
-                nv[b:] = 1  # padded rows: degenerate but well-defined
-                scores, nv = self._put(scores, nv)
-                result: RouteBatchResult = self.backend.route_batch(
-                    scores, self.router, n_valid=nv)
-            with tracer.span("pull"):
-                (buf,) = self._pull(b, result.decision)
-                tiers, diff, metrics = unpack_decision(buf, result.metric)
-            if obs_on:  # the pull forced the device sync above
-                self._m_dispatch_s.observe(self.obs.clock.now() - t0)
-            with tracer.span("decide"):
-                decision = self.policy.decide(tiers, diff, metrics,
-                                              self_scores=self_scores)
-                first_id, metric_name, recalibrated = self._record_batch(
-                    decision.tiers, diff, decision, backend_tiers=tiers)
-        if not return_details:
-            return decision.tiers
-        return BatchDispatchResult(tiers=decision.tiers, difficulty=diff,
-                                   metrics=metrics, first_id=first_id,
-                                   metric=metric_name,
-                                   recalibrated=recalibrated,
-                                   request_cost=decision.request_cost,
-                                   depths=decision.depths)
+        # always pass a concrete n_valid so every bucket shape compiles
+        # the kernel exactly once (None vs array would be two traces);
+        # padding rows hold one valid score: degenerate but well-defined
+        nv = np.full(b, k, np.int32)
+        if n_valid is not None:
+            nv[:] = np.asarray(n_valid, np.int32)
+        result, _ = self._dispatch(
+            "dispatch", b,
+            lambda bpad: (_pad_rows(scores, bpad), _pad_rows(nv, bpad, 1)),
+            lambda s, v: (route(s, router, n_valid=v).decision,),
+            lambda buf: unpack_decision(buf, router.metric),
+            self_scores=self_scores)
+        return result if return_details else result.tiers
 
     def dispatch_retrieved(self, feats: np.ndarray, query_emb: np.ndarray,
                            scorer_params, n_cand: Optional[np.ndarray] = None
                            ) -> "RetrievedDispatchResult":
         """End-to-end dispatch from candidate features: ONE device program
         (scoring -> top-k -> skew -> decision; see
-        `repro.core.router.route_retrieved`) replaces the old
-        score-on-device / top-k-on-host / re-enter-device-for-metrics
-        staging. Telemetry and streaming calibration update exactly as
-        for :meth:`dispatch_batch`.
+        `repro.core.router.route_retrieved`). Telemetry and streaming
+        calibration update exactly as for :meth:`dispatch_batch`.
 
         ``feats``: [B, N, Dt]; ``query_emb``: [B, Dq]; ``n_cand``:
-        optional [B] real candidate counts (ragged retrieval).
+        optional [B] real candidate counts (ragged retrieval). The
+        bucket's padding rows hold no candidates.
         """
-        if not hasattr(self.backend, "route_retrieved"):
-            raise TypeError(
-                f"difficulty backend {self.backend.name!r} has no "
-                f"route_retrieved; end-to-end dispatch needs one of the "
-                f"built-in backends (oracle | pallas | fused | auto) or a "
-                f"custom backend implementing it")
-        tracer = self.obs.tracer
+        route, router = self._backend_entry("route_retrieved"), self.router
         feats = np.asarray(feats)
-        b, k_feats, _ = feats.shape
-        obs_on = self.obs.enabled
-        with tracer.span("dispatch_retrieved", batch=b):
-            t0 = self.obs.clock.now() if obs_on else 0.0
-            with tracer.span("launch"):
-                bpad = bucket_size(b, BATCH_BUCKETS)
-                qemb = np.asarray(query_emb)
-                nc = np.full(bpad, k_feats, np.int32)
-                if n_cand is not None:
-                    nc[:b] = np.asarray(n_cand, np.int32)
-                nc[b:] = 1  # padded rows: degenerate but well-defined
-                if bpad != b:
-                    feats = np.concatenate(
-                        [feats, np.zeros((bpad - b,) + feats.shape[1:],
-                                         feats.dtype)])
-                    qemb = np.concatenate(
-                        [qemb, np.zeros((bpad - b, qemb.shape[1]),
-                                        qemb.dtype)])
-                feats, qemb, nc = self._put(feats, qemb, nc)
-                res = self.backend.route_retrieved(
-                    feats, qemb, scorer_params, self.router, n_cand=nc)
-            with tracer.span("pull"):
-                tiers, diff, metrics, nv_out, probs, indices = self._pull(
-                    b, res.tiers, res.difficulty, res.metrics, res.n_valid,
+        qemb = np.asarray(query_emb)
+        b, n, _ = feats.shape
+        nc = np.full(b, n, np.int32)
+        if n_cand is not None:
+            nc[:] = np.asarray(n_cand, np.int32)
+
+        def launch(f, q, c):
+            res = route(f, q, scorer_params, router, n_cand=c)
+            return (res.tiers, res.difficulty, res.metrics, res.n_valid,
                     res.probs, res.indices)
-            if obs_on:
-                self._m_dispatch_s.observe(self.obs.clock.now() - t0)
-            with tracer.span("decide"):
-                decision = self.policy.decide(tiers, diff, metrics)
-                first_id, metric_name, recalibrated = self._record_batch(
-                    decision.tiers, diff, decision, backend_tiers=tiers)
-        if decision.depths is not None:
-            nv_out, probs, indices = _cut_to_depths(decision.depths, nv_out,
-                                                    probs, indices)
-        return RetrievedDispatchResult(
-            result=BatchDispatchResult(
-                tiers=decision.tiers, difficulty=diff,
-                metrics=metrics, first_id=first_id,
-                metric=metric_name, recalibrated=recalibrated,
-                request_cost=decision.request_cost,
-                depths=decision.depths),
-            indices=indices,
-            probs=probs,
-            n_valid=nv_out)
+        result, (nv_out, probs, indices) = self._dispatch(
+            "dispatch_retrieved", b,
+            lambda bpad: (_pad_rows(feats, bpad), _pad_rows(qemb, bpad),
+                          _pad_rows(nc, bpad)),
+            launch, lambda *pulled: pulled)
+        return RetrievedDispatchResult(result=result, indices=indices,
+                                       probs=probs, n_valid=nv_out)
 
     def dispatch_kg(self, store, scorer_params, topics, query_emb,
                     cand_ids, n_cand) -> QuestionDispatchResult:
@@ -471,12 +465,7 @@ class SkewRouteDispatcher:
         skips all their tiles. ``kg_scorer_tiles_total{state}`` counts the
         scorer grid's tiles of each call, scored and skipped.
         """
-        if not hasattr(self.backend, "route_kg"):
-            raise TypeError(
-                f"difficulty backend {self.backend.name!r} has no route_kg; "
-                f"question dispatch needs one of the built-in backends "
-                f"(oracle | pallas | fused | auto) or a custom backend "
-                f"implementing it")
+        route, router = self._backend_entry("route_kg"), self.router
         topics = np.asarray(topics)
         cand = np.asarray(cand_ids)
         nc = np.asarray(n_cand, np.int32)
@@ -494,57 +483,29 @@ class SkewRouteDispatcher:
                 f"candidate counts must lie in 1 .. {n} and the store holds "
                 f"{store.max_cands} slots; got {n} columns, counts "
                 f"{nc.min(initial=1)} .. {nc.max(initial=1)}")
-        tracer = self.obs.tracer
-        obs_on = self.obs.enabled
-        with tracer.span("dispatch_kg", batch=b):
-            t0 = self.obs.clock.now() if obs_on else 0.0
-            with tracer.span("launch"):
-                bpad = bucket_size(b, BATCH_BUCKETS)
-                ids = np.zeros((bpad, store.max_cands), np.int32)
-                ids[:b, :n] = np.where(np.arange(n)[None, :] < nc[:, None],
-                                       cand, 0)
-                if ids.min() < 0 or ids.max() >= store.n_triples or \
-                        topics.min(initial=0) < 0 or \
-                        topics.max(initial=0) >= store.n_entities:
-                    raise ValueError(
-                        f"candidate triple ids must lie in 0 .. "
-                        f"{store.n_triples - 1} and topics in 0 .. "
-                        f"{store.n_entities - 1}")
-                top = np.zeros(bpad, np.int32)
-                top[:b] = topics
-                q = np.zeros((bpad, store.d_emb), np.float32)
-                q[:b] = qemb
-                ncp = np.zeros(bpad, np.int32)  # padded rows: no slots
-                ncp[:b] = nc
-                metric = self.router.metric
-                packed = self.backend.route_kg(
-                    store, scorer_params, self.router,
-                    *self._put(top, q, ids, ncp))
-            with tracer.span("pull"):
-                (buf,) = self._pull(b, packed)
-                tiers, diff, metrics, nv_out, probs, triple_ids = unpack_kg(
-                    buf, metric)
-            if obs_on:
-                self._m_dispatch_s.observe(self.obs.clock.now() - t0)
-            self._m_kg_cands.inc(int(nc.sum()))
-            scored = int((-(-nc // SCORER_TILE)).sum())
-            self._m_kg_tiles_scored.inc(scored)
-            self._m_kg_tiles_skipped.inc(
-                bpad * -(-store.max_cands // SCORER_TILE) - scored)
-            with tracer.span("decide"):
-                decision = self.policy.decide(tiers, diff, metrics)
-                first_id, metric_name, recalibrated = self._record_batch(
-                    decision.tiers, diff, decision, backend_tiers=tiers)
-        if decision.depths is not None:
-            nv_out, probs, triple_ids = _cut_to_depths(
-                decision.depths, nv_out, probs, triple_ids)
-        return QuestionDispatchResult(
-            result=BatchDispatchResult(
-                tiers=decision.tiers, difficulty=diff, metrics=metrics,
-                first_id=first_id, metric=metric_name,
-                recalibrated=recalibrated,
-                request_cost=decision.request_cost, depths=decision.depths),
-            triple_ids=triple_ids, probs=probs, n_valid=nv_out)
+        ids = np.zeros((b, store.max_cands), np.int32)
+        ids[:, :n] = np.where(np.arange(n)[None, :] < nc[:, None], cand, 0)
+        if ids.min(initial=0) < 0 or ids.max(initial=0) >= store.n_triples \
+                or topics.min(initial=0) < 0 or \
+                topics.max(initial=0) >= store.n_entities:
+            raise ValueError(
+                f"candidate triple ids must lie in 0 .. "
+                f"{store.n_triples - 1} and topics in 0 .. "
+                f"{store.n_entities - 1}")
+        result, (nv_out, probs, triple_ids) = self._dispatch(
+            "dispatch_kg", b,
+            lambda bpad: tuple(_pad_rows(x, bpad) for x in (
+                topics.astype(np.int32), qemb, ids, nc)),
+            lambda *inputs: (route(store, scorer_params, router, *inputs),),
+            lambda buf: unpack_kg(buf, router.metric))
+        bpad = bucket_size(b, BATCH_BUCKETS)
+        self._m_kg_cands.inc(int(nc.sum()))
+        scored = int((-(-nc // SCORER_TILE)).sum())
+        self._m_kg_tiles_scored.inc(scored)
+        self._m_kg_tiles_skipped.inc(
+            bpad * -(-store.max_cands // SCORER_TILE) - scored)
+        return QuestionDispatchResult(result=result, triple_ids=triple_ids,
+                                      probs=probs, n_valid=nv_out)
 
     def _record_batch(self, tiers: np.ndarray, diff: np.ndarray,
                       decision=None, backend_tiers=None

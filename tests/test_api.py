@@ -270,7 +270,14 @@ def test_backend_registry_and_auto():
     auto = make_backend("auto")
     assert isinstance(auto, backends_mod.AutoBackend)
     assert auto.crossover_batch == backends_mod.DEFAULT_CROSSOVER_BATCH
-    assert isinstance(make_backend("fused"), backends_mod.FusedBackend)
+    # one kernel backend class, made under either name and reporting it
+    for name in ("pallas", "fused"):
+        kernel = make_backend(name)
+        assert isinstance(kernel, PallasBackend) and kernel.name == name
+        session = build(RouteSpec(backend=name, thresholds=(0.0,), top_k=8,
+                                  tier_names=("a", "b")))
+        assert session.telemetry()["backend"] == name
+        assert session.snapshot()["policy"]["backend"] == name
     with pytest.raises(ValueError, match="unknown difficulty backend"):
         make_backend("quantum")
     with pytest.raises(ValueError, match="invalid backend name"):

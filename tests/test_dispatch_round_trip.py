@@ -15,7 +15,10 @@ interpret mode off-TPU), with dense and ragged ``n_valid``:
   decision program, and no ``atleast_2d`` program.
 
 The end-to-end ``dispatch_retrieved`` path gets the same checks against
-``core.router.route_retrieved``.
+``core.router.route_retrieved``; a warmed question dispatch
+(``dispatch_kg``) launches only its program too. All three entries pad a
+batch to its bucket alike: score rows with one valid score, candidate
+rows with none, and nothing downstream sees a padding row.
 """
 
 import glob
@@ -25,17 +28,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.api import RouteSpec, build
+from repro.api import RouteSpec, build, make_backend
 from repro.core import router
 from repro.core.router import route_retrieved
 from repro.kernels.device import default_interpret
 from repro.obs import ManualClock, Observability
+from repro.retrieval.store import DeviceKG
 from repro.serving.router_service import BATCH_BUCKETS
 from repro.serving.scheduler import bucket_size
 
 K = 100
 CROSSOVER = 32
 D_TRIPLE, D_QUERY, D_HIDDEN, N_CAND = 12, 8, 16, 64
+#: The question path's graph: entities, relations, triples and each
+#: question's candidate slots; embeddings are D_QUERY wide.
+N_ENT, N_REL, N_TRIPLES, MAX_CANDS = 50, 5, 400, 32
 
 #: (batch, the side of auto's crossover its padded bucket lands on)
 SIDES = [(5, "oracle"), (40, "fused")]
@@ -46,12 +53,12 @@ THRESHOLDS = {"area": (9.0, 25.0), "cumulative": (18.0, 54.0),
               "entropy": (4.2, 5.8), "gini": (-0.4, -0.1)}
 
 
-def _session(obs=None, metric="gini"):
+def _session(obs=None, metric="gini", **kg):
     spec = RouteSpec(metric=metric, thresholds=THRESHOLDS[metric], top_k=K,
                      tier_names=("small", "medium", "large"),
                      crossover_batch=CROSSOVER, micro_batch=8)
     return build(spec, runners={t: (lambda batch: batch) for t in range(3)},
-                 obs=obs or Observability(clock=ManualClock()))
+                 obs=obs or Observability(clock=ManualClock()), **kg)
 
 
 def _rows(rng, b, ragged):
@@ -73,6 +80,26 @@ def _features(rng, b, ragged):
     nc = (rng.integers(1, N_CAND + 1, b).astype(np.int32) if ragged
           else None)
     return feats, qemb, nc
+
+
+def _kg(rng):
+    """A seeded graph on the device and seeded scorer weights for it."""
+    store = DeviceKG.put(
+        rng.normal(0, 1, (N_ENT, D_QUERY)), rng.normal(0, 1, (N_REL, D_QUERY)),
+        rng.integers(0, N_ENT, N_TRIPLES), rng.integers(0, N_REL, N_TRIPLES),
+        rng.integers(0, N_ENT, N_TRIPLES), max_cands=MAX_CANDS)
+    shapes = (("w1_t", (3 * D_QUERY + 14, D_HIDDEN)),
+              ("w1_q", (D_QUERY, D_HIDDEN)), ("b1", (D_HIDDEN,)),
+              ("w2", (D_HIDDEN, 1)), ("b2", (1,)))
+    return store, {name: jnp.asarray(rng.normal(0, 0.3, s).astype(np.float32))
+                   for name, s in shapes}
+
+
+def _questions(rng, b):
+    """``b`` questions: topics, queries, candidate ids and ragged counts."""
+    return (rng.integers(0, N_ENT, b), rng.normal(0, 1, (b, D_QUERY)),
+            rng.integers(0, N_TRIPLES, (b, MAX_CANDS)),
+            rng.integers(1, MAX_CANDS + 1, b))
 
 
 def _pad(x, bpad):
@@ -197,7 +224,7 @@ def _launched_programs(trace_dir) -> list[str]:
 
 
 @pytest.mark.parametrize("path", ["batch_oracle", "batch_fused",
-                                  "retrieved"])
+                                  "retrieved", "kg_oracle", "kg_fused"])
 def test_a_warmed_dispatch_launches_only_its_decision_program(path,
                                                               tmp_path):
     rng = np.random.default_rng(23)
@@ -209,6 +236,15 @@ def test_a_warmed_dispatch_launches_only_its_decision_program(path,
         def call():
             session.route_retrieved(feats, qemb, params, n_cand=nc)
         program = "PjitFunction(_retrieved_program)"
+    elif path.startswith("kg_"):
+        store, params = _kg(rng)
+        session = _session(store=store, scorer=params)
+        # 5 questions pad to bucket 8 (oracle), 40 to bucket 64 (fused)
+        questions = _questions(rng, 5 if path == "kg_oracle" else 40)
+
+        def call():
+            session.submit_questions(*questions)
+        program = "PjitFunction(_kg_program)"
     else:
         scores, nv = _rows(rng, 5 if path == "batch_oracle" else 40, True)
 
@@ -223,3 +259,65 @@ def test_a_warmed_dispatch_launches_only_its_decision_program(path,
         jax.profiler.stop_trace()
     launched = _launched_programs(tmp_path)
     assert launched and set(launched) == {program}   # no atleast_2d
+
+
+class _RecordingBackend:
+    """The oracle backend behind every entry, keeping the host copy of the
+    valid-score or candidate counts each entry receives."""
+
+    name = "recording"
+
+    def __init__(self):
+        self.oracle = make_backend("oracle")
+        self.counts = []
+
+    def route_batch(self, scores, config, n_valid=None):
+        self.counts.append(np.asarray(n_valid))
+        return self.oracle.route_batch(scores, config, n_valid=n_valid)
+
+    def route_retrieved(self, feats, query_emb, params, config, n_cand=None):
+        self.counts.append(np.asarray(n_cand))
+        return self.oracle.route_retrieved(feats, query_emb, params, config,
+                                           n_cand=n_cand)
+
+    def route_kg(self, store, params, config, topics, query_emb, cand_ids,
+                 n_cand):
+        self.counts.append(np.asarray(n_cand))
+        return self.oracle.route_kg(store, params, config, topics, query_emb,
+                                    cand_ids, n_cand)
+
+
+@pytest.mark.parametrize("b", [5, 37])
+@pytest.mark.parametrize("entry", ["dispatch_batch", "dispatch_retrieved",
+                                   "dispatch_kg"])
+def test_padding_rows_hold_one_score_or_no_candidates_and_stay_unseen(
+        entry, b, monkeypatch):
+    rng = np.random.default_rng(41 + b)
+    d = _session().dispatcher
+    d.backend = backend = _RecordingBackend()
+    decided = []
+    decide = d.policy.decide
+    monkeypatch.setattr(d.policy, "decide", lambda tiers, diff, metrics, **kw:
+                        decided.append((len(tiers), len(diff), len(metrics)))
+                        or decide(tiers, diff, metrics, **kw))
+    if entry == "dispatch_batch":
+        scores, real = _rows(rng, b, True)
+        res = d.dispatch_batch(scores, n_valid=real, return_details=True)
+        pad = 1                      # one valid score: well-defined metrics
+    elif entry == "dispatch_retrieved":
+        feats, qemb, real = _features(rng, b, True)
+        res = d.dispatch_retrieved(feats, qemb, _params(rng),
+                                   n_cand=real).result
+        pad = 0                      # no candidates: the scorer skips them
+    else:
+        store, params = _kg(rng)
+        topics, qemb, cand, real = _questions(rng, b)
+        res = d.dispatch_kg(store, params, topics, qemb, cand, real).result
+        pad = 0
+    (counts,) = backend.counts
+    bpad = bucket_size(b, BATCH_BUCKETS)
+    np.testing.assert_array_equal(
+        counts, np.concatenate([real, np.full(bpad - b, pad)]))
+    assert decided == [(b, b, b)]
+    assert len(res.tiers) == len(res.difficulty) == len(res.metrics) == b
+    assert d.stats.n_requests == sum(d.stats.tier_counts.values()) == b

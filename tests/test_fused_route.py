@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 import repro.api.backends as backends_mod
-from repro.api import (AutoBackend, FusedBackend, OracleBackend, RouteSpec,
+from repro.api import (AutoBackend, OracleBackend, PallasBackend, RouteSpec,
                        build, make_backend)
 from repro.core.router import (RouterConfig, route_retrieved,
                                route_retrieved_staged)
@@ -169,7 +169,8 @@ def test_auto_crossover_pick_boundaries():
     assert auto.pick(4) is auto.fused
     assert auto.pick(4096) is auto.fused
     assert isinstance(auto.oracle, OracleBackend)
-    assert isinstance(auto.fused, FusedBackend)
+    assert isinstance(auto.fused, PallasBackend)
+    assert (auto.oracle.name, auto.fused.name) == ("oracle", "fused")
 
 
 def test_auto_routes_by_leading_dim():

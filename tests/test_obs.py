@@ -255,25 +255,59 @@ def test_backend_pick_counter_tracks_crossover():
     assert obs.metrics.value("backend_pick_total", path="fused") == 1
 
 
+#: The small graph of the question-path tests: embedding width, hidden
+#: width, entities and triples.
+KG_D, KG_H, KG_ENT, KG_TRI = 8, 16, 50, 400
+
+
+def _small_kg(rng, max_cands):
+    """A seeded graph of ``KG_ENT`` entities, 5 relations and ``KG_TRI``
+    triples on the device, and seeded scorer weights."""
+    from repro.retrieval.store import DeviceKG
+    store = DeviceKG.put(rng.normal(0, 1, (KG_ENT, KG_D)),
+                         rng.normal(0, 1, (5, KG_D)),
+                         rng.integers(0, KG_ENT, KG_TRI),
+                         rng.integers(0, 5, KG_TRI),
+                         rng.integers(0, KG_ENT, KG_TRI),
+                         max_cands=max_cands)
+    params = {k: rng.normal(0, 0.3, s).astype(np.float32)
+              for k, s in (("w1_t", (3 * KG_D + 14, KG_H)),
+                           ("w1_q", (KG_D, KG_H)), ("b1", (KG_H,)),
+                           ("w2", (KG_H, 1)), ("b2", (1,)))}
+    return store, params
+
+
+def _served_session(obs, rng):
+    """A session with two identity runners and a small graph on the
+    device, crossover 32, micro-batches of 8."""
+    store, params = _small_kg(rng, max_cands=32)
+    return build(mk_spec(crossover_batch=32, micro_batch=8),
+                 runners={0: lambda b: b, 1: lambda b: b}, obs=obs,
+                 store=store, scorer=params)
+
+
 def _dispatch_calls(session, rng):
-    """A submit on each side of auto's crossover (buckets 8 and 64) and
-    one end-to-end retrieve dispatch."""
+    """A submit on each side of auto's crossover (buckets 8 and 64), one
+    end-to-end retrieve dispatch and one submit of 3 questions (bucket
+    8)."""
     import jax.numpy as jnp
     params = {k: jnp.asarray(rng.normal(0, 0.3, s).astype(np.float32))
               for k, s in (("w1_t", (12, 16)), ("w1_q", (8, 16)),
                            ("b1", (16,)), ("w2", (16, 1)), ("b2", (1,)))}
     feats = rng.normal(0, 1, (3, 64, 12)).astype(np.float32)
     qemb = rng.normal(0, 1, (3, 8)).astype(np.float32)
+    question = (rng.integers(0, KG_ENT, 3), rng.normal(0, 1, (3, KG_D)),
+                rng.integers(0, KG_TRI, (3, 32)), np.asarray([32, 3, 17]))
     return [lambda: session.submit(desc_scores(rng, 5)),
             lambda: session.submit(desc_scores(rng, 40)),
-            lambda: session.route_retrieved(feats, qemb, params)]
+            lambda: session.route_retrieved(feats, qemb, params),
+            lambda: session.submit_questions(*question)]
 
 
 @pytest.mark.parametrize("plane", ["null", "enabled"])
 def test_dispatch_transfers_counter_reads_one_each_way_per_dispatch(plane):
     obs = Observability(clock=ManualClock()) if plane == "enabled" else None
-    session = build(mk_spec(crossover_batch=32, micro_batch=8),
-                    runners={0: lambda b: b, 1: lambda b: b}, obs=obs)
+    session = _served_session(obs, np.random.default_rng(16))
     for n, call in enumerate(_dispatch_calls(session,
                                              np.random.default_rng(13)), 1):
         call()
@@ -284,7 +318,7 @@ def test_dispatch_transfers_counter_reads_one_each_way_per_dispatch(plane):
             assert obs.metrics.value("dispatch_transfers_total",
                                      direction=direction) == n
     if obs is not None:
-        assert obs.metrics.value("backend_pick_total", path="oracle") == 2
+        assert obs.metrics.value("backend_pick_total", path="oracle") == 3
         assert obs.metrics.value("backend_pick_total", path="fused") == 1
 
 
@@ -377,14 +411,13 @@ def _forest(events) -> list:
 
 
 def _served_path_calls(obs, trace_dir):
-    """Three calls, each in a ``bench.call`` annotation: submit on each
-    side of auto's crossover, and end-to-end retrieve dispatch. Returns
-    the trace's forest and the micro-batches each submit executed."""
+    """The four calls of :func:`_dispatch_calls`, each in a
+    ``bench.call`` annotation. Returns the trace's forest and the
+    micro-batches each call executed."""
     import jax
     from jax.profiler import TraceAnnotation
 
-    session = build(mk_spec(crossover_batch=32, micro_batch=8),
-                    runners={0: lambda b: b, 1: lambda b: b}, obs=obs)
+    session = _served_session(obs, np.random.default_rng(17))
     calls = _dispatch_calls(session, np.random.default_rng(11))
     for call in calls:               # compile outside the trace
         call()
@@ -405,14 +438,16 @@ def _served_path_calls(obs, trace_dir):
 def test_served_path_spans_land_in_the_profiler_trace(plane, tmp_path):
     obs = Observability(clock=ManualClock()) if plane == "enabled" else None
     forest, ran = _served_path_calls(obs, tmp_path)
-    assert [r[0] for r in forest] == ["bench.call"] * 3
+    assert [r[0] for r in forest] == ["bench.call"] * 4
     stages = [["repro.launch", []], ["repro.pull", []],
               ["repro.decide", []]]
-    for (_, call), n_exec in zip(forest[:2], ran[:2]):
+    for (_, call), n_exec, span in zip(
+            [forest[i] for i in (0, 1, 3)], [ran[i] for i in (0, 1, 3)],
+            ["repro.dispatch", "repro.dispatch", "repro.dispatch_kg"]):
         # one submit a call: dispatch, then the hand-off with one execute
         # span per micro-batch the call filled
         assert call == [["repro.submit", [
-            ["repro.dispatch", stages],
+            [span, stages],
             ["repro.handoff", [["repro.execute", []]] * n_exec]]]]
     assert ran[1] >= 4                # 40 rows fill at least 4 of 8
     assert forest[2][1] == [["repro.dispatch_retrieved", stages]]
@@ -424,29 +459,21 @@ def test_question_dispatch_spans_counters_and_store_gauge(tmp_path):
     candidates scored, and the store's bytes."""
     import jax
     from jax.profiler import TraceAnnotation
-    from repro.retrieval.store import DeviceKG
 
     rng = np.random.default_rng(14)
-    d, h, n_ent, n_tri = 8, 16, 50, 400
-    store = DeviceKG.put(rng.normal(0, 1, (n_ent, d)), rng.normal(0, 1, (5, d)),
-                         rng.integers(0, n_ent, n_tri),
-                         rng.integers(0, 5, n_tri), rng.integers(0, n_ent, n_tri),
-                         max_cands=32)
-    params = {k: rng.normal(0, 0.3, s).astype(np.float32)
-              for k, s in (("w1_t", (3 * d + 14, h)), ("w1_q", (d, h)),
-                           ("b1", (h,)), ("w2", (h, 1)), ("b2", (1,)))}
+    store, params = _small_kg(rng, max_cands=32)
     obs = Observability(clock=ManualClock())
     session = build(mk_spec(top_k=10, micro_batch=2),
                     runners={0: lambda b: b, 1: lambda b: b}, obs=obs,
                     store=store, scorer=params)
     assert obs.metrics.value("kg_store_bytes") == store.nbytes == \
-        4 * (n_ent * d + 5 * d + 3 * n_tri)
+        4 * (KG_ENT * KG_D + 5 * KG_D + 3 * KG_TRI)
     n_cand = np.asarray([32, 3, 17])
 
     def call():
         return session.submit_questions(
-            rng.integers(0, n_ent, 3), rng.normal(0, 1, (3, d)),
-            rng.integers(0, n_tri, (3, 32)), n_cand)
+            rng.integers(0, KG_ENT, 3), rng.normal(0, 1, (3, KG_D)),
+            rng.integers(0, KG_TRI, (3, 32)), n_cand)
     call()                            # compile outside the trace
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -475,17 +502,8 @@ def test_scorer_tile_counter_counts_the_ragged_grid():
     candidates in bucket 64 over 512 slots score four 128-slot tiles each
     (148) and skip the other 108 of the bucket's 256, the 27 padding
     rows' tiles; then 9 questions of 1-129 candidates score 1 or 2."""
-    from repro.retrieval.store import DeviceKG
-
     rng = np.random.default_rng(15)
-    d, h, n_ent, n_tri = 8, 16, 50, 400
-    store = DeviceKG.put(rng.normal(0, 1, (n_ent, d)), rng.normal(0, 1, (5, d)),
-                         rng.integers(0, n_ent, n_tri),
-                         rng.integers(0, 5, n_tri), rng.integers(0, n_ent, n_tri),
-                         max_cands=512)
-    params = {k: rng.normal(0, 0.3, s).astype(np.float32)
-              for k, s in (("w1_t", (3 * d + 14, h)), ("w1_q", (d, h)),
-                           ("b1", (h,)), ("w2", (h, 1)), ("b2", (1,)))}
+    store, params = _small_kg(rng, max_cands=512)
     obs = Observability(clock=ManualClock())
     session = build(mk_spec(top_k=10, micro_batch=2),
                     runners={0: lambda b: b, 1: lambda b: b}, obs=obs,
@@ -498,8 +516,8 @@ def test_scorer_tile_counter_counts_the_ragged_grid():
                             (9, [1, 128, 129, 64, 1, 2, 3, 4, 5],
                              [148 + 10, 108 + 246])):
         session.submit_questions(
-            rng.integers(0, n_ent, b), rng.normal(0, 1, (b, d)),
-            rng.integers(0, n_tri, (b, 512)), np.asarray(counts))
+            rng.integers(0, KG_ENT, b), rng.normal(0, 1, (b, KG_D)),
+            rng.integers(0, KG_TRI, (b, 512)), np.asarray(counts))
         assert tiles() == want
 
 
