@@ -409,32 +409,42 @@ def subgraph_hops(topics: jax.Array, heads: jax.Array, tails: jax.Array,
     return dh, relax(dt, into_tail, dh)
 
 
-def kg_features(ent, rel, heads, rels, tails, topics, query_emb, cand_ids,
-                n_cand) -> jax.Array:
-    """A question batch's candidate features, gathered on the device:
-    ``[B, N]`` candidate triple ids (valid below ``n_cand``) -> ``[B, N,
-    3d + 2(MAX_DDE_HOPS+1) + 4]``: head, relation and tail rows, the DDE
-    one-hots of head and tail over the question's subgraph, and q.h, q.r,
-    q.t, q.(h+r) over sqrt(d) at ``HIGHEST`` precision. A padded slot
-    reads triple 0, so every gather stays in bounds; it is no edge of the
-    subgraph, and the decision masks it out."""
-    from repro.kernels.triple_score.kernel import PRECISION
+def kg_parts(ent, rel, heads, rels, tails, topics, cand_ids, n_cand):
+    """A question batch's candidate features as parts, gathered on the
+    device: ``[B, N]`` candidate triple ids (valid below ``n_cand``) ->
+    head, relation and tail rows (``[B, N, d]`` each) and ``[B, N, 2]``
+    int32 hop distances of head and tail over the question's subgraph.
+    The scorer builds the rest of the feature row, the DDE one-hots and
+    q.h, q.r, q.t, q.(h+r) over sqrt(d), from these
+    (:func:`score_parts`). A padded slot reads triple 0, so every gather
+    stays in bounds; it is no edge of the subgraph, and the decision masks
+    it out."""
     from repro.retrieval.scorer import MAX_DDE_HOPS
 
     n = cand_ids.shape[1]
     valid = jnp.arange(n, dtype=jnp.int32)[None, :] < n_cand[:, None]
     ids = jnp.where(valid, cand_ids, 0)
     hid, rid, tid = heads[ids], rels[ids], tails[ids]
-    h, r, t = ent[hid], rel[rid], ent[tid]
     dh, dt = subgraph_hops(topics, hid, tid, valid, MAX_DDE_HOPS)
-    eye = jnp.eye(MAX_DDE_HOPS + 1, dtype=jnp.float32)
-    qv = query_emb.astype(jnp.float32) / np.sqrt(np.float32(ent.shape[1]))
+    return ent[hid], rel[rid], ent[tid], jnp.stack([dh, dt], axis=-1)
 
-    def sim(x):
-        return jnp.einsum("bnd,bd->bn", x, qv, precision=PRECISION)
 
-    sims = jnp.stack([sim(h), sim(r), sim(t), sim(h + r)], axis=-1)
-    return jnp.concatenate([h, r, t, eye[dh], eye[dt], sims], axis=-1)
+def score_parts(h, r, t, hops, query_emb, w1_t, w1_q, b1, w2, b2, *,
+                use_kernels: bool, interpret: bool, tile: int,
+                n_cand: jax.Array) -> jax.Array:
+    """The parts of :func:`kg_parts` + [B, d] queries -> [B, N] candidate
+    logits, the scores of the feature block ``[h | r | t | onehot(dh) |
+    onehot(dt) | sims]`` without building it: the Pallas
+    `triple_score_parts` kernel, which skips the tiles past each row's
+    ``n_cand``, or its XLA ref, which scores every slot."""
+    if use_kernels:
+        from repro.kernels.triple_score import kernel as ts_kernel
+        return ts_kernel.triple_score_parts(
+            h, r, t, hops, query_emb, w1_t, w1_q, b1, w2, b2, n_cand=n_cand,
+            tile=tile, interpret=interpret)
+    from repro.kernels.triple_score.ref import triple_score_parts_ref
+    return triple_score_parts_ref(h, r, t, hops, query_emb, w1_t, w1_q, b1,
+                                  w2, b2)
 
 
 def pack_kg(tiers, metrics, n_valid, probs, triple_ids) -> jax.Array:
@@ -466,14 +476,14 @@ def _kg_program(ent, rel, heads, rels, tails, w1_t, w1_q, b1, w2, b2,
                 thresholds, topics, query_emb, cand_ids, n_cand, *,
                 top_k: int, metric: str, p_cdf: float, use_kernels: bool,
                 interpret: bool, tile: int) -> jax.Array:
-    """Candidate triple ids -> features from the on-device tables ->
+    """Candidate triple ids -> feature parts from the on-device tables ->
     triple scores -> top-k -> skew metrics -> tiers, and the top-k triple
     ids, as ONE jitted program with ONE output (:func:`pack_kg`)."""
-    feats = kg_features(ent, rel, heads, rels, tails, topics, query_emb,
-                        cand_ids, n_cand)
-    logits = score_candidates(feats, query_emb, w1_t, w1_q, b1, w2, b2,
-                              use_kernels=use_kernels, interpret=interpret,
-                              tile=tile, n_cand=n_cand)
+    h, r, t, hops = kg_parts(ent, rel, heads, rels, tails, topics, cand_ids,
+                             n_cand)
+    logits = score_parts(h, r, t, hops, query_emb, w1_t, w1_q, b1, w2, b2,
+                         use_kernels=use_kernels, interpret=interpret,
+                         tile=tile, n_cand=n_cand)
     idx, probs, nv, tiers, _, metrics = topk_sigmoid_decision(
         logits, thresholds, n_cand, top_k=top_k, metric=metric, p_cdf=p_cdf,
         ragged=True, use_kernel=use_kernels, interpret=interpret)

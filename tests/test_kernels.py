@@ -100,6 +100,53 @@ def test_triple_score_batched_skips_tiles_past_each_count(tile, n):
         assert (ragged[row, stop:] == kernel.FILL).all()
 
 
+@pytest.mark.parametrize("n,counts", [
+    (512, [0, 1, 127, 128, 129, 512, 0, 37]),   # a 0 between real rows
+    (300, [129, 0, 300, 1, 0]),                  # N off the tile
+    (200, None),                                 # every tile scored
+])
+def test_triple_score_parts_matches_block_and_parts_refs(n, counts):
+    """The parts kernel scores what the block kernel scores on the
+    concatenated block ``[h | r | t | onehot(dh) | onehot(dt) | sims]``,
+    and what the parts' XLA ref scores, on each row's real slots; the
+    tiles past a row's count read the fill."""
+    from repro.kernels.triple_score import kernel
+    from repro.kernels.triple_score.ref import (triple_score_batched_ref,
+                                                triple_score_parts_ref)
+    b, d, h, m, tile = len(counts or [0, 0, 0]), 64, 64, 5, 128
+    ks = jax.random.split(jax.random.key(n), 10)
+    heads, rels, tails = (jax.random.normal(ks[i], (b, n, d))
+                          for i in range(3))
+    hops = jax.random.randint(ks[3], (b, n, 2), 0, m)
+    query = jax.random.normal(ks[4], (b, d))
+    weights = (jax.random.normal(ks[5], (3 * d + 2 * m + 4, h)) * 0.2,
+               jax.random.normal(ks[6], (d, h)) * 0.2,
+               jax.random.normal(ks[7], (h,)) * 0.1,
+               jax.random.normal(ks[8], (h, 1)) * 0.2,
+               jax.random.normal(ks[9], (1,)))
+    parts = (heads, rels, tails, hops, query, *weights)
+    n_cand = None if counts is None else jnp.asarray(counts, jnp.int32)
+    got = np.asarray(kernel.triple_score_parts(*parts, n_cand=n_cand,
+                                               tile=tile, interpret=True))
+    eye = jnp.eye(m, dtype=jnp.float32)
+    qv = query / jnp.sqrt(jnp.float32(d))
+    sims = jnp.stack([jnp.einsum("bnd,bd->bn", x, qv,
+                                 precision=jax.lax.Precision.HIGHEST)
+                      for x in (heads, rels, tails, heads + rels)], axis=-1)
+    block = jnp.concatenate([heads, rels, tails, eye[hops[..., 0]],
+                             eye[hops[..., 1]], sims], axis=-1)
+    want_block = np.asarray(triple_score_batched_ref(block, query, *weights))
+    want_parts = np.asarray(triple_score_parts_ref(*parts))
+    scored = (np.full(b, n) if counts is None
+              else np.minimum(-(-np.asarray(counts) // tile) * tile, n))
+    for row, stop in enumerate(scored):
+        np.testing.assert_allclose(got[row, :stop], want_parts[row, :stop],
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(got[row, :stop], want_block[row, :stop],
+                                   rtol=1e-5, atol=1e-5)
+        assert (got[row, stop:] == kernel.FILL).all()
+
+
 @given(st.integers(1, 30), st.integers(5, 100), st.integers(0, 100))
 def test_skew_metrics_property(rows, k, seed):
     from repro.kernels.skew_metrics.kernel import skew_metrics

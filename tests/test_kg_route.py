@@ -120,10 +120,18 @@ def test_submit_questions_matches_plain_reference(kg, b):
                                atol=1e-5)
     assert set(res.tiers.tolist()) == {0, 1}   # both tiers exercised
 
-    # the program's features and logits beside the reference's
+    # the program's feature parts, concatenated here, and its logits from
+    # the parts on both sides, beside the reference's
     dev = [jnp.asarray(x) for x in (topics, query, cand, n_cand)]
-    got = np.asarray(jax.jit(router.kg_features)(
-        store.ent, store.rel, store.heads, store.rels, store.tails, *dev))
+    h, r, t, hops = (np.asarray(x) for x in jax.jit(router.kg_parts)(
+        store.ent, store.rel, store.heads, store.rels, store.tails, dev[0],
+        dev[2], dev[3]))
+    eye = np.eye(MAX_DDE_HOPS + 1, dtype=np.float32)
+    qv = query / np.sqrt(np.float32(D))
+    sims = np.stack([np.einsum("bnd,bd->bn", x, qv)
+                     for x in (h, r, t, h + r)], axis=-1)
+    got = np.concatenate([h, r, t, eye[hops[..., 0]], eye[hops[..., 1]],
+                          sims], axis=-1)
     mask = np.arange(MAX_CANDS)[None, :] < n_cand[:, None]
     np.testing.assert_allclose(got[mask], feats[mask], rtol=0, atol=1e-5)
     w = {k: np.asarray(v, np.float64) for k, v in params.items()}
@@ -132,12 +140,14 @@ def test_submit_questions_matches_plain_reference(kg, b):
         h = np.maximum(f @ w["w1_t"] + (query.astype(np.float64)
                                         @ w["w1_q"])[:, None] + w["b1"], 0)
         return (h @ w["w2"])[..., 0] + w["b2"][0]
-    lg = np.asarray(router.score_candidates(
-        jnp.asarray(got), dev[1], *(params[k] for k in
-                                     ("w1_t", "w1_q", "b1", "w2", "b2")),
-        use_kernels=False, interpret=True, tile=128))
-    np.testing.assert_allclose(lg[mask], logits(feats.astype(np.float64))[mask],
-                               rtol=0, atol=LOGIT_ATOL)
+    want = logits(feats.astype(np.float64))[mask]
+    for use_kernels in (False, True):
+        lg = np.asarray(router.score_parts(
+            *(jnp.asarray(x) for x in (h, r, t, hops)), dev[1],
+            *(params[k] for k in ("w1_t", "w1_q", "b1", "w2", "b2")),
+            use_kernels=use_kernels, interpret=True, tile=128,
+            n_cand=dev[3]))
+        np.testing.assert_allclose(lg[mask], want, rtol=0, atol=LOGIT_ATOL)
 
 
 #: hand-built subgraphs: (topic, [(head, tail)], padded (head, tail) slots)

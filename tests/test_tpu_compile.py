@@ -91,6 +91,28 @@ def test_ragged_triple_score_batched_compiles_at_gte_large_width(one_chip):
     assert _kernel_ops(text) == ["triple_score_batched"]
 
 
+def test_triple_score_parts_compiles_at_gte_large_width(one_chip):
+    """The question path's scorer at the cell's widths (bucket 64, 512
+    candidates, d = H = 1024, tile 128): the feature block's parts in,
+    the ragged grid, and the scoped VMEM it asks for, which the default
+    16 MiB does not hold."""
+    b, n, d, tile = 64, 512, 1024, 128
+    dt = 3 * d + 14
+    text = _compiled_text(
+        lambda *a: score_kernel.triple_score_parts(
+            *a[:10], n_cand=a[10], tile=tile, interpret=False),
+        *[_shape(one_chip, (b, n, d))] * 3,
+        _shape(one_chip, (b, n, 2), jnp.int32), _shape(one_chip, (b, d)),
+        *_scorer_shapes(one_chip, dt, d, d),
+        _shape(one_chip, (b,), jnp.int32))
+    assert _kernel_ops(text) == ["triple_score_parts"]
+    limit = score_kernel._parts_vmem(tile, d, d, dt - 3 * d).vmem_limit_bytes
+    assert limit > score_kernel.DEFAULT_SCOPED_VMEM
+    call = next(line for line in text.splitlines()
+                if "tpu_custom_call" in line)
+    assert f'"size":"{limit}"' in call
+
+
 def test_triple_score_shared_candidates_compiles(one_chip):
     text = _compiled_text(
         lambda *a: score_kernel.triple_score(*a, interpret=False),
@@ -127,9 +149,10 @@ def test_fused_retrieve_to_decision_compiles(one_chip):
 
 def test_question_program_compiles_at_gte_large_width(one_chip):
     """`route_kg` at bucket 64 over a store of NSM's WebQSP Freebase size
-    (1,441,420 x 1,024 entity table): the gathers, the scorer at Dt=3086
-    (whose blocks need more than the default scoped VMEM) and the skew
-    kernel, in one program."""
+    (1,441,420 x 1,024 entity table): the gathers, the scorer over the
+    feature parts at Dt=3086 (whose blocks need more than the default
+    scoped VMEM) and the skew kernel, in one program, with no
+    [64, 512, 3086] feature block."""
     n_ent, n_rel, n_tri, d, b, n = 1441420, 6102, 11_300_000, 1024, 64, 512
     dt = 3 * d + 14
 
@@ -144,4 +167,5 @@ def test_question_program_compiles_at_gte_large_width(one_chip):
         _shape(one_chip, (b,), jnp.int32), _shape(one_chip, (b, d)),
         _shape(one_chip, (b, n), jnp.int32), _shape(one_chip, (b,), jnp.int32))
     assert sorted(_kernel_ops(text)) == ["skew_metrics",
-                                         "triple_score_batched"]
+                                         "triple_score_parts"]
+    assert not re.search(rf"= f32\[{b},{n},{dt}\]", text)
